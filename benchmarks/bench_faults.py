@@ -25,11 +25,6 @@ import sys
 STEPS = 60
 
 SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-)
-os.environ["JAX_PLATFORMS"] = "cpu"
 import json
 import time
 
@@ -154,6 +149,12 @@ def run(rows):
     )
     env = dict(os.environ)
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    # the child runs on 8 virtual CPU devices; this process's own
+    # environment is left as it is
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (
+        env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+    )
     proc = subprocess.run(
         [sys.executable, "-c", SCRIPT],
         capture_output=True, text=True, env=env, timeout=900,

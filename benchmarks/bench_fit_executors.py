@@ -98,7 +98,7 @@ def _phase_decomposition(data):
     The sum approximates one mesh_topk fit; the differences attribute
     the local↔mesh gap to a phase.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     st = api.GradientDescent(lsq_loss, lr=0.05)
@@ -145,7 +145,7 @@ def _phase_decomposition(data):
     coll = jax.jit(
         shard_map(
             coll_body, mesh=r.mesh, in_specs=P(r.axis), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
     )
     t_coll, _ = _timed_raw(coll, msgs)

@@ -30,24 +30,20 @@ import sys
 STEPS = 200
 
 SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = (
-    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-)
-os.environ["JAX_PLATFORMS"] = "cpu"
 import json
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import api
 from repro.api import executor as X
 from repro.core.allreduce import hierarchical_allreduce
 from repro.core.topology import calibrate_prices
+from repro.launch.mesh import make_multipod_mesh
 from repro.ml.linear import lsq_loss
 from repro.telemetry.hlo import collective_stats, mesh_pod_map
 
@@ -59,7 +55,7 @@ w = jnp.asarray(rng.normal(size=(N,)))
 y = jnp.einsum("kni,i->kn", Xs, w)
 data = (Xs, y)
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_multipod_mesh(num_pods=2)
 
 
 def timed(fn, repeats=3):
@@ -100,7 +96,7 @@ def round_aggregate(stacked):
 
 g = jax.jit(shard_map(
     round_aggregate, mesh=mesh, in_specs=P(r.axis), out_specs=P(),
-    check_rep=False,
+    check_vma=False,
 ))
 txt = g.lower(jnp.ones((K, N))).compile().as_text()
 measured = collective_stats(txt, pod_of=mesh_pod_map(mesh))
@@ -117,7 +113,7 @@ def hop_loop(axes):
         )[0]
     return jax.jit(shard_map(
         body, mesh=mesh, in_specs=P(r.axis), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     ))
 
 msg = jnp.ones((K, N))
@@ -186,6 +182,12 @@ def run(rows):
     )
     env = dict(os.environ)
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    # the child runs on 8 virtual CPU devices; this process's own
+    # environment is left as it is
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = (
+        env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+    )
     proc = subprocess.run(
         [sys.executable, "-c", SCRIPT],
         capture_output=True, text=True, env=env, timeout=900,

@@ -2,8 +2,9 @@
 
 Prints ``name,us_per_call,derived`` CSV.  Each bench module imports
 independently: an import failure (missing optional dep, broken
-accelerator stack) reports a ``SKIP(import)`` row and the rest of the
-suite still runs.
+accelerator stack) reports a ``SKIP(import)`` row and a failed run an
+``ERROR`` row, the rest of the suite still runs, and the harness then
+exits nonzero, naming the modules that did not run.
 
 The executor/multipod/serve benches additionally embed a
 ``run_report_md`` block (``telemetry.report.RunReport`` rendered to
@@ -44,6 +45,7 @@ def main() -> None:
     args = ap.parse_args()
 
     rows: list = []
+    failed: list = []
     print("name,us_per_call,derived")
     for name, modpath in MODULES.items():
         if args.only and args.only not in name:
@@ -54,6 +56,7 @@ def main() -> None:
             err = traceback.format_exc().splitlines()[-1]
             print(f"{name},SKIP(import),{err}")
             sys.stdout.flush()
+            failed.append(name)
             continue
         try:
             start = len(rows)
@@ -63,6 +66,9 @@ def main() -> None:
                 sys.stdout.flush()
         except Exception:  # noqa: BLE001 — print and continue
             print(f"{name},ERROR,{traceback.format_exc().splitlines()[-1]}")
+            failed.append(name)
+    if failed:
+        sys.exit(f"benchmarks that did not run: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

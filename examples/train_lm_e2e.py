@@ -58,7 +58,7 @@ def main():
                 "--log-every", str(max(args.steps // 10, 1)),
                 "--ckpt-dir", ckpt, "--ckpt-every", str(args.steps // 2),
             ]
-        )
+        )["history"]
         assert hist[-1]["loss"] < hist[0]["loss"], "training must improve"
 
         # --- restore the final checkpoint and serve it

@@ -60,7 +60,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.allreduce import (
@@ -853,12 +853,12 @@ class MeshExecutor(Executor):
                 inner = shard_map(
                     lambda c, d: shard_body(c, d, None), mesh=mesh,
                     in_specs=(cspec, dspec), out_specs=(cspec, P()),
-                    check_rep=False,
+                    check_vma=False,
                 )
                 return jax.jit(lambda c, d, x: inner(c, d))
             return jax.jit(shard_map(
                 shard_body, mesh=mesh, in_specs=(cspec, dspec, P()),
-                out_specs=(cspec, P()), check_rep=False,
+                out_specs=(cspec, P()), check_vma=False,
             ))
 
         key = None if cache_key is None else (
@@ -934,7 +934,7 @@ class MeshExecutor(Executor):
         def build():
             return jax.jit(shard_map(
                 body, mesh=mesh, in_specs=(cspec, P(axis), P()),
-                out_specs=(cspec, P()), check_rep=False,
+                out_specs=(cspec, P()), check_vma=False,
             ))
 
         key = None if cache_key is None else (

@@ -202,7 +202,7 @@ def calibrate_prices(
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = tuple(mesh.axis_names)
@@ -227,7 +227,7 @@ def calibrate_prices(
                 mesh=mesh,
                 in_specs=P(),
                 out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )
         )
         jax.block_until_ready(fn(x))  # compile outside the timed region

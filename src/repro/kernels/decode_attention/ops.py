@@ -38,10 +38,13 @@ def decode_attention(
     vl = jnp.broadcast_to(jnp.asarray(valid_len).reshape(-1), (B,))
     bias = jnp.where(jnp.arange(Sp)[None, :] < vl[:, None], 0.0, NEG_INF).astype(
         jnp.float32
-    )
+    )[:, None, :]
 
     qg = q.reshape(B, Hkv, G, D)
-    out = decode_attention_fwd(qg, k, v, bias, bk=bk_eff, interpret=interpret)
+    out = decode_attention_fwd(
+        qg, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), bias,
+        bk=bk_eff, interpret=interpret,
+    )
     return out.reshape(B, Hq, D)
 
 

@@ -23,9 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships this as TPUCompilerParams; newer releases renamed it.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 BLOCK = 1024
 ROWS = 8
 LANE = 128
@@ -80,7 +77,7 @@ def absmax(x: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
         out_specs=pl.BlockSpec((1, LANE), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, LANE), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, LANE), jnp.float32)],
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(blocks)
     return jnp.max(lanes)

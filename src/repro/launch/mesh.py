@@ -10,17 +10,23 @@ from __future__ import annotations
 
 import jax
 
+from repro.sharding.rules import auto_axes
+
+
+def _make_mesh(shape: tuple, axes: tuple):
+    return auto_axes(jax.make_mesh(shape, axes))
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke tests / examples."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _make_mesh((1, 1), ("data", "model"))
 
 
 def make_node_mesh(num_devices: int | None = None):
@@ -28,7 +34,7 @@ def make_node_mesh(num_devices: int | None = None):
     default placement for the paper's K logical nodes (K must be a multiple
     of the device count; each device hosts K/ndev nodes)."""
     n = num_devices if num_devices is not None else len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return _make_mesh((n,), ("data",))
 
 
 def make_multipod_mesh(num_pods: int | None = None, num_devices: int | None = None):
@@ -42,7 +48,7 @@ def make_multipod_mesh(num_pods: int | None = None, num_devices: int | None = No
         num_pods = 2 if n % 2 == 0 else 1
     if n % num_pods:
         raise ValueError(f"{n} devices do not split into {num_pods} pods")
-    return jax.make_mesh((num_pods, n // num_pods), ("pod", "data"))
+    return _make_mesh((num_pods, n // num_pods), ("pod", "data"))
 
 
 def batch_axes(mesh) -> tuple:

@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.models import transformer as tf
+from repro.utils.compile_cache import enable_compile_cache
 
 
 # ----------------------------------------------------------------------------
@@ -326,6 +327,7 @@ def main(argv=None):
                     help="place the engine on a mesh over all local devices")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if not args.requests:
         args.requests = args.batch

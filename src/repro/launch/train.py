@@ -50,6 +50,7 @@ from repro.configs import get_config
 from repro.data import synthetic_lm_batches
 from repro.models import transformer as tf
 from repro.optim import adam, clip_by_global_norm, warmup_cosine
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _chunk_end(done: int, steps: int, log_every: int, ckpt_every: int) -> int:
@@ -109,6 +110,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -176,6 +178,7 @@ def main(argv=None):
     history = []
     theta, carry, done = params, None, 0
     wire_bytes = 0
+    kernel_hits = None
     while done < args.steps:
         end = _chunk_end(done, args.steps, args.log_every, args.ckpt_every)
         batches = [next(data) for _ in range(end - done)]
@@ -194,6 +197,7 @@ def main(argv=None):
             tag="train",
         )
         theta, carry = res.theta, res.metrics["carry"]
+        kernel_hits = res.metrics.get("wire_kernel_hits")
         if sweep_levels is None:
             wire_bytes += res.ledger.uplink_bytes
             losses = {"loss": float(res.trajectory[-1])}
@@ -216,18 +220,14 @@ def main(argv=None):
         if args.ckpt_dir and args.ckpt_every and done % args.ckpt_every == 0:
             save(args.ckpt_dir, done, theta)
     final = {k: v for k, v in history[-1].items() if k != "step"}
-    print(
-        json.dumps(
-            {
-                "final_loss": (
-                    final["loss"] if sweep_levels is None else final
-                ),
-                "uplink_bytes": wire_bytes,
-                "history": history,
-            }
-        )
-    )
-    return history
+    summary = {
+        "final_loss": final["loss"] if sweep_levels is None else final,
+        "uplink_bytes": wire_bytes,
+        "wire_kernel_hits": kernel_hits,
+        "history": history,
+    }
+    print(json.dumps(summary))
+    return summary
 
 
 if __name__ == "__main__":

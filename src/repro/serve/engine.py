@@ -36,7 +36,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.launch.mesh import batch_axes, data_axis_size
 from repro.serve.metrics import ServeMetrics
-from repro.sharding.rules import place_params
+from repro.sharding.rules import auto_axes, place_params
 from repro.telemetry import trace as _trace
 
 PyTree = Any
@@ -77,7 +77,7 @@ class ServeEngine:
         tracer=None,
     ):
         self.strategy = strategy
-        self.mesh = mesh
+        self.mesh = auto_axes(mesh) if mesh is not None else None
         self.model_axis = model_axis
         self.fsdp_axis = fsdp_axis
         self.tag = tag

@@ -22,9 +22,22 @@ from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 _ctx = threading.local()
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis of type Auto.
+
+    ``jax.make_mesh`` builds Explicit axes on JAX 0.9, and on Explicit
+    axes ``with_sharding_constraint`` and scatter/gather over sharded
+    operands raise.  This repo places arrays by constraint
+    (``maybe_shard``) and by ``device_put`` (``place_params``) and lets
+    the compiler propagate the rest, so the ``launch.mesh`` builders,
+    ``MeshContext`` and ``ServeEngine`` pass their meshes through here.
+    """
+    return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 @dataclass
@@ -33,6 +46,9 @@ class MeshContext:
     # logical axis name -> physical mesh axis (or tuple of axes) or None
     logical: dict = field(default_factory=dict)
     fsdp: bool = False
+
+    def __post_init__(self):
+        self.mesh = auto_axes(self.mesh)
 
     @property
     def batch_axes(self):

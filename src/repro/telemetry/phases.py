@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.allreduce import hierarchical_allreduce, mesh_allreduce
@@ -173,7 +173,7 @@ def profile_phases(
 
         return jax.jit(shard_map(
             body, mesh=r.mesh, in_specs=P(r.axis), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         ))
 
     for hop in r.topology.hops:
@@ -188,7 +188,7 @@ def profile_phases(
 
     stats_prog = jax.jit(shard_map(
         stats_body, mesh=r.mesh, in_specs=P(r.axis), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     ))
     K = strategy.num_nodes(data)
     _fenced(

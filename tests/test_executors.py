@@ -913,7 +913,7 @@ class TestTopologyLedger:
     def test_hierarchical_allreduce_flat_hop_is_mesh_allreduce(self):
         """A single flat hop over all node axes is exactly the joint
         collective (the bit-exact degradation the refactor promises)."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.core.allreduce import hierarchical_allreduce, mesh_allreduce

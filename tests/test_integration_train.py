@@ -14,7 +14,7 @@ def test_train_loss_decreases():
             "--arch", "tinyllama-1.1b", "--reduced", "--steps", "60",
             "--batch", "8", "--seq", "64", "--log-every", "20", "--lr", "1e-3",
         ]
-    )
+    )["history"]
     assert hist[-1]["loss"] < hist[0]["loss"] - 0.1
 
 
@@ -25,7 +25,7 @@ def test_train_with_staleness_and_compression():
             "--batch", "4", "--seq", "32", "--log-every", "20",
             "--staleness", "2", "--compress-topk", "0.2", "--lr", "1e-3",
         ]
-    )
+    )["history"]
     assert all(jnp.isfinite(jnp.asarray(h["loss"])) for h in hist)
     assert hist[-1]["loss"] < hist[0]["loss"] + 0.5  # no divergence
 

@@ -33,7 +33,7 @@ def test_collective_stats_detects_psum():
         ) + 0.0
 
     # force an all-reduce via shard_map psum
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     g = shard_map(
         lambda v: jax.lax.psum(v, "x"),
@@ -134,7 +134,7 @@ def test_collective_stats_pod_attribution_real_lowering():
     """A real staged hierarchical psum lowers to collectives whose
     replica groups classify as intra- then inter-pod (single-device runs
     degenerate to intra-pod only)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.allreduce import hierarchical_allreduce

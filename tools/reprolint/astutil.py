@@ -182,7 +182,7 @@ TRACING_CALLS = {
     "jax.lax.fori_loop": (2,),
     "jax.lax.cond": (1, 2),
     "jax.lax.switch": (1,),
-    "jax.experimental.shard_map.shard_map": (0,),
+    "jax.shard_map": (0,),
     "jax.experimental.pallas.pallas_call": (0,),
 }
 
@@ -259,7 +259,7 @@ _REASONS = {
     "jax.lax.fori_loop": "fori_loop body",
     "jax.lax.cond": "cond branch",
     "jax.lax.switch": "switch branch",
-    "jax.experimental.shard_map.shard_map": "shard_map body",
+    "jax.shard_map": "shard_map body",
     "jax.experimental.pallas.pallas_call": "pallas kernel",
 }
 
