@@ -158,7 +158,10 @@ def fit(
         (under ``executor="serve"``) the serving engine's spans all land
         on one timeline.  All spans are host-side: a traced fit runs the
         same compiled program and returns bit-identical results
-        (``tests/test_trace.py``).  No tracer → zero overhead.
+        (``tests/test_trace.py``).  Without one, the ambient tracer
+        (``trace.activated``), if any, records the run; with neither,
+        zero overhead.  The whole call is a ``fit/call`` span, the
+        parent of ``fit/loop``, ``fit/ledger`` and ``fit/metrics``.
       trace: ``"phases"`` (requires ``tracer``) additionally recovers
         per-phase DEVICE timings — local-step, wire encode, per-hop
         collective, stats completion — by replaying standalone
@@ -172,7 +175,7 @@ def fit(
         raise ValueError(f"trace must be None or 'phases', got {trace!r}")
     if trace == "phases" and tracer is None:
         raise ValueError("trace='phases' requires a tracer=Tracer()")
-    with _trace.activated(tracer):
+    with _trace.activated(tracer), _trace.span("fit/call", tag=tag):
         return _fit_traced(
             strategy, data, wire=wire, transport=transport,
             executor=executor, sweep=sweep, schedule=schedule, steps=steps,
@@ -198,7 +201,7 @@ def _fit_traced(
             wire=w, schedule=schedule, steps=steps, stream=stream,
             theta0=theta0, carry=carry, executor=ex, faults=plan,
         )
-        if tracer is not None:
+        if _trace.current_tracer() is not None:
             # fence so the loop span covers device completion, not just
             # async dispatch — a pure wait, results unchanged
             jax.block_until_ready(raw.theta)

@@ -453,12 +453,15 @@ class UpdateTransport(Transport):
                     # complete LAST round's outermost hop first, so the
                     # collective overlaps the local compute traced below
                     agg_done = _exec.aggregate_complete(pending)
-                msgs, sstate = strategy.local_updates(
-                    theta, sstate, shard_data, batch
-                )
-                wstate_new, msgs_hat, up = wire.encode_updates(
-                    wstate, msgs, stacked=strategy.stacked_msgs
-                )
+                # named scopes name the device work in profiler traces
+                with jax.named_scope("local_updates"):
+                    msgs, sstate = strategy.local_updates(
+                        theta, sstate, shard_data, batch
+                    )
+                with jax.named_scope("wire_encode"):
+                    wstate_new, msgs_hat, up = wire.encode_updates(
+                        wstate, msgs, stacked=strategy.stacked_msgs
+                    )
                 if faults is not None:
                     # participation: node k answers iff u_t[k] clears the
                     # (possibly swept, traced) threshold.  The global mask
@@ -549,7 +552,8 @@ class UpdateTransport(Transport):
                     new_c = jax.tree.map(
                         lambda n, o: jnp.where(proceed, n, o), new_c, c0
                     )
-                with _exec.deferring(stats if defer_ok else None):
+                with _exec.deferring(stats if defer_ok else None), \
+                        jax.named_scope("round_metric"):
                     m = strategy.round_metric(new_c[0], new_c[1], shard_data)
                 return new_c, (m, up, down)
 

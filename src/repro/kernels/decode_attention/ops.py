@@ -28,12 +28,15 @@ def decode_attention(
     bk_eff = min(bk, S)
 
     pad = (-S) % bk_eff
-    if pad:
-        widths = [(0, 0)] * 4
-        widths[1] = (0, pad)
-        k = jnp.pad(k, widths)
-        v = jnp.pad(v, widths)
     Sp = S + pad
+    with jax.named_scope("kv_gather"):
+        # pad to whole KV blocks and lay K/V out head-major for the kernel
+        if pad:
+            widths = [(0, 0)] * 4
+            widths[1] = (0, pad)
+            k = jnp.pad(k, widths)
+            v = jnp.pad(v, widths)
+        k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
 
     vl = jnp.broadcast_to(jnp.asarray(valid_len).reshape(-1), (B,))
     bias = jnp.where(jnp.arange(Sp)[None, :] < vl[:, None], 0.0, NEG_INF).astype(
@@ -42,8 +45,7 @@ def decode_attention(
 
     qg = q.reshape(B, Hkv, G, D)
     out = decode_attention_fwd(
-        qg, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2), bias,
-        bk=bk_eff, interpret=interpret,
+        qg, k, v, bias, bk=bk_eff, interpret=interpret,
     )
     return out.reshape(B, Hq, D)
 

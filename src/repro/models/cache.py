@@ -20,6 +20,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 
@@ -138,12 +139,13 @@ def paged_view(cache: PagedKVCache, block: jnp.ndarray):
     n_slots, pp = block.shape
     P = cache.k.shape[1]
     tail = cache.k.shape[2:]
-    k = jnp.take(cache.k, block.reshape(-1), axis=0)
-    v = jnp.take(cache.v, block.reshape(-1), axis=0)
-    return (
-        k.reshape(n_slots, pp * P, *tail),
-        v.reshape(n_slots, pp * P, *tail),
-    )
+    with jax.named_scope("kv_gather"):
+        k = jnp.take(cache.k, block.reshape(-1), axis=0)
+        v = jnp.take(cache.v, block.reshape(-1), axis=0)
+        return (
+            k.reshape(n_slots, pp * P, *tail),
+            v.reshape(n_slots, pp * P, *tail),
+        )
 
 
 def paged_append(
@@ -160,12 +162,13 @@ def paged_append(
     collide there — by design).
     """
     P = cache.k.shape[1]
-    page = jnp.take_along_axis(block, (length // P)[:, None], axis=1)[:, 0]
-    off = length % P
-    return PagedKVCache(
-        k=cache.k.at[page, off].set(k_tok.astype(cache.k.dtype)),
-        v=cache.v.at[page, off].set(v_tok.astype(cache.v.dtype)),
-    )
+    with jax.named_scope("kv_append"):
+        page = jnp.take_along_axis(block, (length // P)[:, None], axis=1)[:, 0]
+        off = length % P
+        return PagedKVCache(
+            k=cache.k.at[page, off].set(k_tok.astype(cache.k.dtype)),
+            v=cache.v.at[page, off].set(v_tok.astype(cache.v.dtype)),
+        )
 
 
 def paged_write(

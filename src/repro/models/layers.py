@@ -33,10 +33,12 @@ def dense(p, x, *, compute_dtype=None):
     k = p["kernel"]
     if compute_dtype is not None:
         x = x.astype(compute_dtype)
-    k = k.astype(x.dtype)
+    with jax.named_scope("weight_cast"):
+        k = k.astype(x.dtype)
+        b = p["bias"].astype(x.dtype) if "bias" in p else None
     y = x @ k
-    if "bias" in p:
-        y = y + p["bias"].astype(y.dtype)
+    if b is not None:
+        y = y + b
     return y
 
 

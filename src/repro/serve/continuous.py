@@ -362,6 +362,11 @@ class ContinuousLMEngine:
             joined += 1
         return joined
 
+    def _span(self, name: str, **tags):
+        """A span on the engine's tracer; a null context without one."""
+        tr = self.tracer
+        return nullcontext() if tr is None else tr.span(name, **tags)
+
     def _join(self, req: _Request, slot: int) -> None:
         """Prefill the prompt (dense B=1 path, power-of-two bucket) and
         scatter the result into the slot's pages; the first generated
@@ -369,10 +374,8 @@ class ContinuousLMEngine:
         P = len(req.prompt)
         bucket = 1 << max(0, (P - 1).bit_length())
         tr = self.tracer
-        with (
-            tr.span("serve/prefill", prompt=P, bucket=bucket, slot=slot)
-            if tr is not None else nullcontext()
-        ):
+        with self._span("serve/prefill", rid=req.rid, prompt=P, bucket=bucket,
+                        slot=slot):
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :P] = req.prompt
             dense = tf.init_cache(
@@ -386,6 +389,10 @@ class ContinuousLMEngine:
                 self._cache, dense, jnp.asarray(self.sched.block[slot]),
                 jnp.asarray(np.int32(P)),
             )
+            if tr is not None:
+                # the span covers the device's prefill and insert; the
+                # first-token sample below waits for them anyway
+                jax.block_until_ready(self._cache)
         first = self._sample_host(logits[0, P - 1], req.seed, P - 1)
         req.tokens.append(int(first))
         self.sched.length[slot] = P
@@ -424,29 +431,27 @@ class ContinuousLMEngine:
     def step(self) -> int:
         """One scheduler tick: admit what fits, advance every slot one
         token, retire finished requests.  Returns tokens produced."""
-        with self._lock:
+        with self._lock, self._span("serve/step"):
             self._admit_from_backlog()
             active = [s for s, r in enumerate(self.sched.slots) if r is not None]
             if not active:
                 return 0
             n_slots = self.sched.n_slots
-            tr = self.tracer
             t0 = time.perf_counter()
             try:
-                with (
-                    tr.span("serve/decode_step", active=len(active),
-                            slots=n_slots)
-                    if tr is not None else nullcontext()
-                ):
-                    nxt, self._cache = self._step(
-                        self.params,
-                        jnp.asarray(self._last_tok[:, None]),
-                        self._cache,
-                        jnp.asarray(self.sched.block),
-                        jnp.asarray(self.sched.length),
-                        jnp.asarray(self._seeds),
-                    )
-                    nxt = np.asarray(jax.block_until_ready(nxt))
+                with self._span("serve/decode_step", active=len(active),
+                                slots=n_slots):
+                    with self._span("serve/decode_step.inputs"):
+                        nxt, self._cache = self._step(
+                            self.params,
+                            jnp.asarray(self._last_tok[:, None]),
+                            self._cache,
+                            jnp.asarray(self.sched.block),
+                            jnp.asarray(self.sched.length),
+                            jnp.asarray(self._seeds),
+                        )
+                    with self._span("serve/decode_step.wait"):
+                        nxt = np.asarray(jax.block_until_ready(nxt))
             except BaseException as e:
                 # fail every in-flight ticket NOW — a dead decode loop
                 # must not leave callers hanging until their timeout
@@ -455,17 +460,17 @@ class ContinuousLMEngine:
                     req.ticket._fail(e)
                 raise
             dt = time.perf_counter() - t0
-            self.metrics.record_decode_step(len(active), n_slots, dt)
-            self.kernel_hits[self._impl] += len(active)
-            if tr is not None:
-                tr.count("serve/decode_tokens", len(active))
-                tr.gauge("serve/slot_occupancy", len(active) / n_slots)
-            for s in active:
-                req = self.sched.slots[s]
-                req.tokens.append(int(nxt[s]))
-                self.sched.length[s] += 1
-                self._last_tok[s] = nxt[s]
-                self._retire_if_done(s)
+            with self._span("serve/emit"):
+                self.metrics.record_decode_step(len(active), n_slots, dt)
+                self.kernel_hits[self._impl] += len(active)
+                if self.tracer is not None:
+                    self.tracer.count("serve/decode_tokens", len(active))
+                for s in active:
+                    req = self.sched.slots[s]
+                    req.tokens.append(int(nxt[s]))
+                    self.sched.length[s] += 1
+                    self._last_tok[s] = nxt[s]
+                    self._retire_if_done(s)
             return len(active)
 
     def flush(self, key=None) -> int:

@@ -16,6 +16,10 @@ exports them as Chrome trace-event JSON loadable in Perfetto
     tracer.count("program_cache/hit")
     tracer.export_chrome("run.trace.json")
 
+Each span record carries an ``id`` and the ``parent`` id of the span
+that was open on the same thread when it began (None at the top), so a
+span's self time is its duration minus what its children cover.
+
 **Zero overhead when off.**  Tracing is opt-in twice over: nothing in the
 hot paths allocates or formats unless a tracer is *installed* (the
 ambient ``current_tracer()`` is None by default) and *enabled*.  The
@@ -33,13 +37,16 @@ any orphaned use in ``src/repro``.
 Device time: a host span around a dispatch measures submission, not
 execution.  Call sites that want device-complete timings fence with
 ``jax.block_until_ready`` before closing the span (the engine does this
-for the loop span; ``telemetry.phases`` does it per phase), and
-``device_trace(logdir)`` wraps ``jax.profiler.trace`` so a full XLA
-device trace nests under the same run for Perfetto/TensorBoard.
+for the loop span; ``telemetry.phases`` does it per phase).  An enabled
+tracer's span is also a ``jax.profiler.TraceAnnotation`` of the same
+name, so whenever a profiler trace is running (``jax.profiler.trace``)
+every program span lands on the profiler's host plane, on the same clock
+as the device's operations.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
@@ -72,11 +79,14 @@ class Tracer:
 
     def __init__(self, enabled: bool = True):
         self.enabled = bool(enabled)
-        self.spans: list = []  # dicts: name, ts (us), dur (us), tid, tags
+        #: dicts: name, ts (us), dur (us), tid, tags, id, parent
+        self.spans: list = []
         self.counters: dict = {}
         self.gauges: dict = {}
         self._lock = threading.Lock()
         self._tids: dict = {}  # thread ident -> small stable int
+        self._ids = itertools.count()
+        self._open = threading.local()  # per thread: the open span records
         self.t0_us = _now_us()
 
     # -- recording -----------------------------------------------------------
@@ -89,28 +99,44 @@ class Tracer:
                 tid = self._tids.setdefault(ident, len(self._tids))
         return tid
 
+    def _stack(self) -> list:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
     def span_begin(self, name: str, **tags) -> dict:
         """Open a span record (low level — use ``with tracer.span(...)``;
         the reprolint ``span-discipline`` rule flags direct calls)."""
+        stack = self._stack()
         rec = {
             "name": name,
             "ts": _now_us(),
             "dur": None,
             "tid": self._tid(),
             "tags": tags,
+            "id": next(self._ids),
+            "parent": stack[-1]["id"] if stack else None,
         }
+        stack.append(rec)
         with self._lock:
             self.spans.append(rec)
         return rec
 
     def span_end(self, rec: dict) -> None:
         rec["dur"] = _now_us() - rec["ts"]
+        stack = self._stack()
+        if rec in stack:
+            stack.remove(rec)
 
     @contextmanager
     def _span(self, name: str, tags: dict):
+        from jax.profiler import TraceAnnotation  # lazy: importable without jax
+
         rec = self.span_begin(name, **tags)
         try:
-            yield rec
+            with TraceAnnotation(name):
+                yield rec
         finally:
             self.span_end(rec)
 
@@ -140,21 +166,6 @@ class Tracer:
             return
         with self._lock:
             self.gauges[name] = value
-
-    @contextmanager
-    def device_trace(self, logdir: str):
-        """Nest an XLA device trace (``jax.profiler.trace``) under a span
-        of this run, so the host-side timeline and the device profile
-        land in one place::
-
-            with tracer.device_trace("/tmp/xla-trace"):
-                api.fit(..., tracer=tracer)
-        """
-        import jax
-
-        with self.span("device_trace", logdir=str(logdir)):
-            with jax.profiler.trace(str(logdir)):
-                yield
 
     # -- reading -------------------------------------------------------------
 
@@ -261,7 +272,11 @@ def activated(tracer: Tracer | None):
     """Install ``tracer`` as the ambient tracer for the enclosed block
     (``api.fit(..., tracer=...)`` wraps the whole run in this, so the
     executors' program-cache/dispatch spans land on the same timeline).
-    ``None`` is a no-op install, keeping call sites unconditional."""
+    ``None`` is a no-op install that keeps the enclosing tracer, so call
+    sites stay unconditional.  Yields the tracer in effect inside."""
+    if tracer is None:
+        yield current_tracer()
+        return
     prev = getattr(_active, "value", None)
     _active.value = tracer
     try:

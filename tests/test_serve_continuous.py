@@ -506,7 +506,10 @@ class TestContinuousObservability:
         assert "serve/decode_step" in spans and "serve/prefill" in spans
         assert tr.counters["serve/joins"] == 3
         assert tr.counters["serve/decode_tokens"] == s["tokens"]
-        assert 0 < tr.gauges["serve/slot_occupancy"] <= 1
+        # mean slot occupancy: decoded tokens over slots stepped
+        occupancy = tr.counters["serve/decode_tokens"] / (
+            spans["serve/decode_step"]["count"] * 2)
+        assert 0 < occupancy <= 1
 
         md = RunReport.from_serve(eng).to_markdown()
         assert "decode kernel hits" in md
@@ -514,6 +517,62 @@ class TestContinuousObservability:
         rep = RunReport.from_serve(eng).as_dict()
         assert rep["decode_kernel_hits"]["xla"] + \
                rep["decode_kernel_hits"]["pallas"] == s["tokens"]
+
+    def test_traced_engine_span_tree_and_same_tokens(self, tiny_lm):
+        cfg, params = tiny_lm
+        rng = np.random.default_rng(11)
+        prompts = [rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
+                   for n in (3, 5, 4)]
+
+        def serve(tracer):
+            eng = ContinuousLMEngine(cfg, params, n_slots=2, page_size=4,
+                                     max_seq=16, tracer=tracer)
+            tickets = [eng.submit(p, max_new=g) for p, g in zip(prompts, (4, 2, 3))]
+            eng.run_until_idle()
+            return [np.asarray(t.result()) for t in tickets]
+
+        tr = Tracer()
+        traced, plain = serve(tr), serve(None)
+        assert all(np.array_equal(a, b) for a, b in zip(traced, plain))
+        by_id = {s["id"]: s for s in tr.spans}
+        parent_of = {
+            "serve/step": None, "serve/prefill": "serve/step",
+            "serve/decode_step": "serve/step", "serve/emit": "serve/step",
+            "serve/decode_step.inputs": "serve/decode_step",
+            "serve/decode_step.wait": "serve/decode_step",
+        }
+        for s in tr.spans:
+            up = by_id[s["parent"]]["name"] if s["parent"] is not None else None
+            assert up == parent_of[s["name"]], s["name"]
+        kids = {}
+        for s in tr.spans:
+            if s["parent"] is not None:
+                kids.setdefault(s["parent"], []).append(s["name"])
+        decode = [s for s in tr.spans if s["name"] == "serve/decode_step"]
+        assert decode
+        for s in decode:
+            assert kids[s["id"]] == ["serve/decode_step.inputs", "serve/decode_step.wait"]
+            assert "serve/emit" in kids[s["parent"]]
+        prefill = [s for s in tr.spans if s["name"] == "serve/prefill"]
+        assert sorted(s["tags"]["rid"] for s in prefill) == [0, 1, 2]
+
+    def test_decode_step_names_its_device_work(self):
+        """The compiled decode step's operations carry the page gather,
+        the token's append and the weight casts in their op metadata."""
+        from repro.serve import continuous
+
+        cfg = _tiny_cfg(compute_dtype="bfloat16")
+        params = tf.init_params(jax.random.key(0), cfg)
+        n_slots, page, max_seq = 2, 4, 16
+        pps = max_seq // page
+        cache = tf.init_paged_cache(cfg, 1 + n_slots * pps, page, jnp.bfloat16)
+        i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
+        for impl in ("xla", "pallas"):
+            step = continuous._build_step(cfg, impl, 0.0, False)
+            text = step.lower(params, i32(n_slots, 1), cache, i32(n_slots, pps),
+                              i32(n_slots), i32(n_slots)).compile().as_text()
+            for scope in ("kv_gather", "kv_append", "weight_cast"):
+                assert f"/{scope}/" in text, (impl, scope)
 
     def test_ledger_coalesces_inference_events(self, tiny_lm):
         cfg, params = tiny_lm

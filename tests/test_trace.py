@@ -138,6 +138,72 @@ class TestTracer:
         assert trace_mod.current_tracer() is None
         assert [s["name"] for s in t.spans] == ["ambient"]
 
+    def test_span_parents_nest_as_written(self):
+        t = Tracer()
+        with t.span("outer"):
+            with t.span("inner"):
+                with t.span("leaf"):
+                    pass
+            with t.span("inner"):
+                pass
+        with t.span("next"):
+            pass
+        by = {(s["name"], i): s for i, s in enumerate(t.spans)}
+        outer, inner, leaf, inner2, nxt = (by[k] for k in sorted(by, key=lambda k: k[1]))
+        assert outer["parent"] is None and nxt["parent"] is None
+        assert inner["parent"] == outer["id"] and inner2["parent"] == outer["id"]
+        assert leaf["parent"] == inner["id"]
+        assert len({s["id"] for s in t.spans}) == 5
+
+    def test_activated_none_keeps_the_enclosing_tracer(self, problem):
+        t = Tracer()
+        with trace_mod.activated(t):
+            with trace_mod.activated(None) as inner:
+                assert inner is t and trace_mod.current_tracer() is t
+            assert trace_mod.current_tracer() is t
+            # api.fit(tracer=None) records into the ambient tracer
+            _fit(problem)
+        assert trace_mod.current_tracer() is None
+        names = [s["name"] for s in t.spans]
+        assert names.count("fit/call") == 1 and "fit/loop" in names
+        call = next(s for s in t.spans if s["name"] == "fit/call")
+        for name in ("fit/loop", "fit/ledger", "fit/metrics"):
+            assert next(s for s in t.spans if s["name"] == name)["parent"] == call["id"]
+        dispatch = [s for s in t.spans if s["name"].startswith("dispatch/")]
+        loop = next(s for s in t.spans if s["name"] == "fit/loop")
+        assert dispatch and all(s["parent"] == loop["id"] for s in dispatch)
+
+    def test_spans_land_in_a_profiler_trace(self, tmp_path):
+        """An enabled tracer's span is a profiler annotation too: it shows
+        up on the profile's host plane, nested as written and no longer
+        than the span's in-memory interval."""
+        import time
+        from pathlib import Path
+
+        import jax
+        from jax.profiler import ProfileData
+
+        t = Tracer()
+        with jax.profiler.trace(str(tmp_path)):
+            with t.span("fit/call"):
+                with t.span("dispatch/local-update"):
+                    time.sleep(0.01)
+                with t.span("fit/ledger"):
+                    time.sleep(0.002)
+        (xplane,) = Path(tmp_path).rglob("*.xplane.pb")
+        names = {s["name"] for s in t.spans}
+        got = {e.name: (int(e.start_ns), int(e.start_ns + e.duration_ns))
+               for p in ProfileData.from_file(str(xplane)).planes if p.name.startswith("/host:")
+               for ln in p.lines for e in ln.events if e.name in names}
+        assert set(got) == names
+        rec = {s["name"]: s for s in t.spans}
+        for name, (s, e) in got.items():
+            assert 0 < (e - s) / 1e3 <= rec[name]["dur"]
+        call = got["fit/call"]
+        for child in ("dispatch/local-update", "fit/ledger"):
+            assert call[0] <= got[child][0] and got[child][1] <= call[1]
+        assert got["dispatch/local-update"][1] <= got["fit/ledger"][0]
+
     def test_chrome_export_schema(self, problem, tmp_path):
         """The acceptance criterion: every exported event carries the
         trace-event schema keys, complete events carry dur, and the file
@@ -173,6 +239,28 @@ class TestTracer:
         )
         assert proc.returncode == 0, proc.stderr
         assert "fit/loop" in proc.stdout
+
+
+class TestNamedScopes:
+    def test_fit_program_names_its_device_work(self, problem, monkeypatch):
+        """The round program's operations carry the strategy's local step,
+        the wire's encode and the round's metric in their op metadata
+        (what a profiler trace reads as each operation's scope)."""
+        from repro.api import executor as ex
+
+        built = []
+        dispatch = ex.dispatch
+
+        def capture(key, build, label, *args):
+            built.append((build, args))
+            return dispatch(key, build, label, *args)
+
+        monkeypatch.setattr(ex, "dispatch", capture)
+        _fit(problem, wire="topk:0.5+ef")
+        build, args = built[0]
+        text = build().lower(*args).compile().as_text()
+        for scope in ("local_updates", "wire_encode", "round_metric"):
+            assert f"/{scope}/" in text, scope
 
 
 class TestRunReport:
