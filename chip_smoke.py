@@ -10,9 +10,9 @@ the chip stays with this process.  The phases, at full published width
 with random weights made from ``--seed``:
 
 1. serve — continuous LM serving of ``qwen2-1.5b`` through
-   ``ContinuousLMEngine``: every request resolves on the Pallas decode
-   kernel, in one compiled step, and the kernel agrees with its XLA
-   reference at the serving shapes.
+   ``ContinuousLMEngine``: every request resolves on the paged Pallas
+   decode kernel, in one compiled step, and the kernel agrees with its
+   XLA reference at the serving shapes.
 2. train — ``xlstm-125m`` through ``repro.launch.train.main`` with a
    ``topk`` wire, so the fused top-k encode kernel is on the path; the
    loss stays finite.
@@ -55,6 +55,7 @@ from repro.core import schedules  # noqa: E402
 from repro.kernels.decode_attention import ops as da_ops  # noqa: E402
 from repro.launch import train  # noqa: E402
 from repro.ml.linear import lsq_loss  # noqa: E402
+from repro.models import cache as cache_lib  # noqa: E402
 from repro.models import transformer as tf  # noqa: E402
 from repro.serve import ContinuousLMEngine  # noqa: E402
 from repro.utils.compile_cache import enable_compile_cache  # noqa: E402
@@ -90,21 +91,25 @@ class ServeCase:
 
 
 def decode_kernel_error(cfg, case: ServeCase) -> float:
-    """Max-abs difference between the Pallas decode kernel and
-    ``decode_attention_xla`` on random data at the engine's shapes:
-    ``slots`` rows over ``max_seq`` rounded up to whole pages, in the
-    compute dtype, with ragged valid lengths."""
-    max_seq = case.prompt_len + case.gen
-    S = -(-max_seq // case.page_size) * case.page_size
+    """Max-abs difference between the paged Pallas decode kernel and
+    ``decode_attention_xla`` over ``paged_view`` of the same arena, on
+    random data at the engine's shapes: ``slots`` block rows over
+    ``max_seq`` rounded up to whole pages, shuffled across the arena, in
+    the compute dtype, with ragged valid lengths."""
+    pps = -(-(case.prompt_len + case.gen) // case.page_size)
     B, Hq, Hkv, D = case.slots, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    n_pages = 1 + B * pps
     dt = jnp.dtype(cfg.compute_dtype)
-    kq, kk, kv, kl = jax.random.split(jax.random.key(case.seed + 7), 4)
+    kq, kk, kv, kl, kp = jax.random.split(jax.random.key(case.seed + 7), 5)
     q = jax.random.normal(kq, (B, Hq, D), dt)
-    k = jax.random.normal(kk, (B, S, Hkv, D), dt)
-    v = jax.random.normal(kv, (B, S, Hkv, D), dt)
-    valid = jax.random.randint(kl, (B,), 1, S + 1)
-    got = da_ops.decode_attention(q, k, v, valid)
-    ref = da_ops.decode_attention_xla(q, k, v, valid)
+    arena = cache_lib.PagedKVCache(
+        k=jax.random.normal(kk, (n_pages, Hkv, case.page_size, D), dt),
+        v=jax.random.normal(kv, (n_pages, Hkv, case.page_size, D), dt),
+    )
+    block = (1 + jax.random.permutation(kp, B * pps)).reshape(B, pps)
+    valid = jax.random.randint(kl, (B,), 1, pps * case.page_size + 1)
+    got = da_ops.paged_decode_attention(q, arena.k, arena.v, block, valid)
+    ref = da_ops.decode_attention_xla(q, *cache_lib.paged_view(arena, block), valid)
     return float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32))))
 
 
@@ -138,7 +143,7 @@ def phase_serve(case: ServeCase) -> dict:
     check(all(o.shape == (case.gen,) for o in outs), "wrong generated length")
     check(all(((o >= 0) & (o < cfg.vocab_size)).all() for o in outs),
           "a generated id is outside the vocabulary")
-    check(engine.kernel_plan["path"] == "pallas",
+    check(engine.kernel_plan["kernel"] == "paged_decode_attention",
           f"decode path is {engine.kernel_plan}")
     check(engine.kernel_hits["xla"] == 0 and engine.kernel_hits["pallas"] > 0,
           f"decode kernel hits {engine.kernel_hits}")

@@ -1,4 +1,5 @@
-"""Jit'd wrapper for the decode-attention kernel."""
+"""Jit'd wrappers for the decode-attention kernels: the dense one over a
+per-slot cache, and the paged one over a page arena and its block table."""
 
 from __future__ import annotations
 
@@ -8,6 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention.kernel import NEG_INF, decode_attention_fwd
+from repro.kernels.decode_attention.paged_kernel import paged_decode_attention_fwd
 
 
 @partial(jax.jit, static_argnames=("bk", "interpret"))
@@ -48,6 +50,34 @@ def decode_attention(
         qg, k, v, bias, bk=bk_eff, interpret=interpret,
     )
     return out.reshape(B, Hq, D)
+
+
+@partial(jax.jit, static_argnames=("pages_per_chunk", "interpret"))
+def paged_decode_attention(
+    q: jnp.ndarray,  # (n_slots, Hq, D)
+    k_pages: jnp.ndarray,  # (n_pages, Hkv, P, D) — the page arena
+    v_pages: jnp.ndarray,
+    block: jnp.ndarray,  # (n_slots, pages_per_slot) physical page ids
+    valid_len: jnp.ndarray,  # (n_slots,) valid positions, each ≥ 1
+    *,
+    pages_per_chunk: int = 32,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """One-token attention for every slot, read through the block table:
+    only each slot's ``ceil(valid_len / P)`` live pages are copied from
+    the arena, ``pages_per_chunk`` pages a time.  Same result as
+    ``decode_attention_xla`` over ``paged_view`` of the arena, up to the
+    order of the f32 softmax sums."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    n_slots, Hq, D = q.shape
+    Hkv = k_pages.shape[1]
+    qg = q.reshape(n_slots, Hkv, Hq // Hkv, D)
+    out = paged_decode_attention_fwd(
+        qg, k_pages, v_pages, block, valid_len,
+        pages_per_chunk=pages_per_chunk, interpret=interpret,
+    )
+    return out.reshape(n_slots, Hq, D)
 
 
 @jax.jit

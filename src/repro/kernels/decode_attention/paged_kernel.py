@@ -1,0 +1,195 @@
+"""Pallas TPU decode attention over a paged KV arena, through the block table.
+
+One query token per slot attends to that slot's cached positions, which
+live in fixed-size pages of a shared arena ``(n_pages, Hkv, P, D)`` and
+are addressed through the slot's row of the block table.  The block table
+(flattened) and the per-slot valid lengths arrive as scalar prefetch, so
+the kernel knows before it starts which pages each slot holds; the arena
+is left where XLA placed it (``memory_space=ANY``) and the kernel copies
+only a slot's live pages, ``ceil(valid / P)`` of them, into VMEM.  Pages
+past a slot's length are neither copied nor attended.
+
+Grid: one step per slot, all ``Hkv`` heads of that slot together.  Inside
+a step a loop runs over the slot's live pages in chunks of
+``pages_per_chunk``, double-buffered: the copies of chunk c + 1 run while
+chunk c is computed.  One page of every KV head, ``(Hkv, P, D)``, is one
+contiguous copy; its rows land at ``[:, i·P : (i+1)·P]`` of a head-major
+``(Hkv, chunk·P, D)`` buffer, so each head's keys are one ``(chunk·P, D)``
+matrix for the MXU.
+
+The softmax is the same online merge as ``kernel._decode_kernel`` (m, l,
+acc in f32).  The mask is an iota compare against the valid length; it
+removes positions past the length on the chunk that holds it.  A chunk's
+pages past the slot's last live page are not copied: their value rows are
+zeroed instead, so the masked weights (exact zeros) multiply finite
+values.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.decode_attention.kernel import NEG_INF
+
+
+def _paged_decode_kernel(
+    block_ref,  # SMEM (n_slots · pages_per_slot,) physical page ids
+    valid_ref,  # SMEM (n_slots,) valid positions per slot
+    q_ref,  # (1, Hkv, G, D)
+    k_arena,  # (n_pages, Hkv, P, D), left where XLA placed it
+    v_arena,
+    o_ref,  # (1, Hkv, G, D)
+    k_buf,  # VMEM (2, Hkv, C·P, D)
+    v_buf,
+    sems,  # DMA semaphores (2, 2): [k / v, buffer]
+    m_ref,  # VMEM (Hkv, G, 128) f32
+    l_ref,  # VMEM (Hkv, G, 128) f32
+    acc_ref,  # VMEM (Hkv, G, D) f32
+    *,
+    pages_per_slot: int,
+    pages_per_chunk: int,
+):
+    slot = pl.program_id(0)
+    Hkv, P = k_arena.shape[1], k_arena.shape[2]
+    C = pages_per_chunk
+    valid = valid_ref[slot]
+    n_live = (valid + P - 1) // P
+    n_chunks = (n_live + C - 1) // C
+    row0 = slot * pages_per_slot
+
+    def page_copies(chunk, buf, i):
+        page = block_ref[row0 + chunk * C + i]
+        rows = pl.ds(pl.multiple_of(i * P, P), P)
+        return (
+            pltpu.make_async_copy(k_arena.at[page], k_buf.at[buf, :, rows, :],
+                                  sems.at[0, buf]),
+            pltpu.make_async_copy(v_arena.at[page], v_buf.at[buf, :, rows, :],
+                                  sems.at[1, buf]),
+        )
+
+    def live_in(chunk):
+        return jnp.minimum(C, n_live - chunk * C)
+
+    def start(chunk, buf):
+        def copy(i, carry):
+            k_copy, v_copy = page_copies(chunk, buf, i)
+            k_copy.start()
+            v_copy.start()
+            return carry
+
+        def zero(i, carry):
+            rows = pl.ds(pl.multiple_of(i * P, P), P)
+            v_buf[buf, :, rows, :] = jnp.zeros((Hkv, P, v_buf.shape[-1]), v_buf.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, live_in(chunk), copy, 0)
+        jax.lax.fori_loop(live_in(chunk), C, zero, 0)
+
+    def wait(chunk, buf):
+        def one(i, carry):
+            k_copy, v_copy = page_copies(chunk, buf, i)
+            k_copy.wait()
+            v_copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, live_in(chunk), one, 0)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    start(0, 0)
+
+    def body(chunk, carry):
+        buf = chunk % 2
+
+        @pl.when(chunk + 1 < n_chunks)
+        def _prefetch():
+            start(chunk + 1, 1 - buf)
+
+        wait(chunk, buf)
+        for h in range(Hkv):
+            q = q_ref[0, h].astype(jnp.float32)  # (G, D)
+            k = k_buf[buf, h].astype(jnp.float32)  # (C·P, D)
+            v = v_buf[buf, h].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * (q.shape[-1] ** -0.5)  # (G, C·P)
+            pos = chunk * (C * P) + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1
+            )
+            mask = pos < valid
+            s = jnp.where(mask, s, NEG_INF)
+
+            m_prev = m_ref[h][:, 0:1]  # (G, 1)
+            l_prev = l_ref[h][:, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, body, 0)
+
+    for h in range(Hkv):
+        l = l_ref[h][:, 0:1]
+        denom = jnp.where(l > 0.0, l, 1.0)
+        o_ref[0, h] = (acc_ref[h] / denom).astype(o_ref.dtype)
+
+
+def paged_decode_attention_fwd(
+    q: jnp.ndarray,  # (n_slots, Hkv, G, D)
+    k_pages: jnp.ndarray,  # (n_pages, Hkv, P, D)
+    v_pages: jnp.ndarray,
+    block: jnp.ndarray,  # (n_slots, pages_per_slot) int32
+    valid_len: jnp.ndarray,  # (n_slots,) int32, each ≥ 1
+    *,
+    pages_per_chunk: int,
+    interpret: bool,
+):
+    n_slots, Hkv, G, D = q.shape
+    P = k_pages.shape[2]
+    pps = block.shape[1]
+    C = min(pages_per_chunk, pps)
+    kernel = lambda *refs: _paged_decode_kernel(
+        *refs, pages_per_slot=pps, pages_per_chunk=C
+    )
+    slot_block = pl.BlockSpec((1, Hkv, G, D), lambda s, blk, vl: (s, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_slots,),
+        in_specs=[
+            slot_block,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=slot_block,
+        scratch_shapes=[
+            pltpu.VMEM((2, Hkv, C * P, D), k_pages.dtype),
+            pltpu.VMEM((2, Hkv, C * P, D), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((Hkv, G, 128), jnp.float32),
+            pltpu.VMEM((Hkv, G, 128), jnp.float32),
+            pltpu.VMEM((Hkv, G, D), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_slots, Hkv, G, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+        ),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="paged_decode_attention",
+    )(block.reshape(-1).astype(jnp.int32), valid_len.astype(jnp.int32),
+      q, k_pages, v_pages)

@@ -4,9 +4,12 @@ Reference (XLA) path; the Pallas flash kernel in
 ``repro.kernels.flash_attention`` is a drop-in for the train/prefill core
 (``use_kernel=True`` on TPU), and the single-token decode path can route
 through ``repro.kernels.decode_attention`` (``decode_attn="pallas"``) or
-its bit-equal jitted XLA reference (``decode_attn="xla"`` — the explicit
-fallback ``decode_kernel_plan`` reports).  ``decode_attn="off"`` keeps
-the historical ``_sdpa`` decode math untouched.
+its jitted XLA reference (``decode_attn="xla"`` — the explicit fallback
+``decode_kernel_plan`` reports).  Over a dense cache "pallas" is the
+dense kernel, bit-equal to the reference; over a paged arena it is the
+paged kernel, which reads each slot's live pages through the block table.
+``decode_attn="off"`` keeps the historical ``_sdpa`` decode math
+untouched.
 """
 
 from __future__ import annotations
@@ -87,10 +90,22 @@ def resolve_decode_attn(use_kernel, *, sliding_window: int = 0) -> str:
     return "pallas" if use_kernel else "xla"
 
 
-def decode_kernel_plan(cfg: ModelConfig, *, use_kernel="auto") -> dict:
+#: what each decode path runs, over a dense cache and over a paged arena
+_DECODE_KERNEL = {
+    ("pallas", False): "decode_attention",
+    ("pallas", True): "paged_decode_attention",
+    ("xla", False): "decode_attention_xla",
+    ("xla", True): "paged_view + decode_attention_xla",
+}
+
+
+def decode_kernel_plan(cfg: ModelConfig, *, use_kernel="auto",
+                       paged: bool = False) -> dict:
     """Which implementation the single-token decode path will take, and
     why — the ``kernel_plan``-style report serving surfaces so a run
-    claiming kernel speed can't silently be on the fallback."""
+    claiming kernel speed can't silently be on the fallback.  ``kernel``
+    names what runs: over a paged arena (``paged=True``) the Pallas path
+    is the block-table kernel, the XLA path a page gather first."""
     if cfg.sliding_window > 0:
         return {
             "path": "off",
@@ -106,11 +121,12 @@ def decode_kernel_plan(cfg: ModelConfig, *, use_kernel="auto") -> dict:
         if backend != "tpu":
             reason += " (interpret mode)"
     else:
-        reason = (
-            f"backend={backend} — jitted XLA reference (bit-equal "
-            "to the kernel)"
+        reason = f"backend={backend} — jitted XLA reference " + (
+            "(equal to the paged kernel up to f32 summation order)" if paged
+            else "(bit-equal to the kernel)"
         )
-    return {"path": path, "reason": reason, "backend": backend}
+    return {"path": path, "kernel": _DECODE_KERNEL[path, paged],
+            "reason": reason, "backend": backend}
 
 
 def _decode_attend(q1, k_all, v_all, valid_len, *, impl: str):
@@ -155,10 +171,13 @@ def attn_apply(
 
     A ``PagedKVCache`` cache decodes through the block table instead:
     ``pages=(block, length)`` (slot → page ids, per-slot fill counts) are
-    jit arguments, the new token is scattered into the arena and
-    attention runs on the gathered per-slot view via ``_decode_attend``.
+    jit arguments and the new token is scattered into the arena.  With
+    ``decode_attn="pallas"`` the paged kernel then reads each slot's live
+    pages through the block table; otherwise the XLA reference attends
+    over the gathered per-slot view (``paged_view``).
     ``decode_attn`` ("off" | "xla" | "pallas") statically picks the
-    single-token decode implementation; "off" keeps the ``_sdpa`` path.
+    single-token decode implementation; "off" keeps the ``_sdpa`` path
+    (on a paged cache, the XLA reference).
     """
     B, T, d = x.shape
     H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -199,14 +218,20 @@ def attn_apply(
             raise ValueError("paged decode appends exactly one token")
         if cfg.sliding_window > 0:
             raise ValueError("paged decode needs full causal attention")
+        from repro.kernels.decode_attention import ops as da_ops
+
         block, length = pages
         new_cache = paged_append(cache, block, length, k[:, 0], v[:, 0])
-        k_all, v_all = paged_view(new_cache, block)
-        impl = decode_attn if decode_attn != "off" else "xla"
-        out = _decode_attend(
-            q[:, 0], k_all.astype(cd), v_all.astype(cd), length + 1,
-            impl=impl,
-        )[:, None]  # (B, 1, Hq, D)
+        if decode_attn == "pallas":
+            out = da_ops.paged_decode_attention(
+                q[:, 0], new_cache.k, new_cache.v, block, length + 1
+            )
+        else:
+            k_all, v_all = paged_view(new_cache, block)
+            out = da_ops.decode_attention_xla(
+                q[:, 0], k_all.astype(cd), v_all.astype(cd), length + 1
+            )
+        out = out[:, None]  # (B, 1, Hq, D)
     else:
         S = cache.k.shape[1]
         idx = cache.index

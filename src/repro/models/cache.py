@@ -14,10 +14,18 @@ provisioned below ``n_slots × max_seq_len``; the host-side
 the compiled decode step can keep writing "their" keys without masking —
 the writes land in garbage memory no live sequence can see.  That is what
 makes join/leave a pure data change (no retrace).
+
+The arena is laid out ``(n_pages, H_kv, page_size, D)``: one page of
+every KV head is one contiguous block, and each head's ``(page_size, D)``
+rows are whole TPU tiles, so the paged decode kernel
+(``kernels/decode_attention/paged_kernel.py``) copies a slot's live pages
+straight from the arena.  ``paged_view`` gathers a dense per-slot view for
+the XLA decode path.
 """
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -114,38 +122,39 @@ class PagedKVCache(NamedTuple):
     retraces).
     """
 
-    k: jnp.ndarray  # (n_pages, page_size, H_kv, D)
-    v: jnp.ndarray  # (n_pages, page_size, H_kv, D)
+    k: jnp.ndarray  # (n_pages, H_kv, page_size, D)
+    v: jnp.ndarray  # (n_pages, H_kv, page_size, D)
 
 
 def paged_kv_cache_init(
     n_pages: int, page_size: int, n_kv: int, head_dim: int, dtype
 ) -> PagedKVCache:
     return PagedKVCache(
-        k=jnp.zeros((n_pages, page_size, n_kv, head_dim), dtype),
-        v=jnp.zeros((n_pages, page_size, n_kv, head_dim), dtype),
+        k=jnp.zeros((n_pages, n_kv, page_size, head_dim), dtype),
+        v=jnp.zeros((n_pages, n_kv, page_size, head_dim), dtype),
     )
 
 
 def paged_view(cache: PagedKVCache, block: jnp.ndarray):
-    """Gather each slot's pages into a dense per-slot view.
+    """Gather each slot's pages into a dense per-slot view (the XLA
+    decode path and the reference; the Pallas path reads the arena
+    through the block table instead).
 
     ``block``: (n_slots, pages_per_slot) physical page ids.  Returns
-    ``(k, v)`` of shape (n_slots, pages_per_slot · page_size, H_kv, D) —
-    the contiguous layout the decode-attention kernel wants; positions
-    beyond a slot's length hold stale/null-page garbage and must be
-    masked by the attention's ``valid_len``.
+    ``(k, v)`` of shape (n_slots, pages_per_slot · page_size, H_kv, D);
+    positions beyond a slot's length hold stale/null-page garbage and
+    must be masked by the attention's ``valid_len``.
     """
     n_slots, pp = block.shape
-    P = cache.k.shape[1]
-    tail = cache.k.shape[2:]
+    _, Hkv, P, D = cache.k.shape
+
+    def view(arena):
+        pages = jnp.take(arena, block.reshape(-1), axis=0)
+        pages = pages.reshape(n_slots, pp, Hkv, P, D).transpose(0, 1, 3, 2, 4)
+        return pages.reshape(n_slots, pp * P, Hkv, D)
+
     with jax.named_scope("kv_gather"):
-        k = jnp.take(cache.k, block.reshape(-1), axis=0)
-        v = jnp.take(cache.v, block.reshape(-1), axis=0)
-        return (
-            k.reshape(n_slots, pp * P, *tail),
-            v.reshape(n_slots, pp * P, *tail),
-        )
+        return view(cache.k), view(cache.v)
 
 
 def paged_append(
@@ -161,20 +170,16 @@ def paged_append(
     so the write lands in the trash page (several inactive slots may
     collide there — by design).
     """
-    P = cache.k.shape[1]
+    P = cache.k.shape[2]
     with jax.named_scope("kv_append"):
         page = jnp.take_along_axis(block, (length // P)[:, None], axis=1)[:, 0]
-        off = length % P
-        return PagedKVCache(
-            k=cache.k.at[page, off].set(k_tok.astype(cache.k.dtype)),
-            v=cache.v.at[page, off].set(v_tok.astype(cache.v.dtype)),
-        )
+        return _scatter_rows(cache, page, length % P, k_tok, v_tok)
 
 
 def paged_write(
     cache: PagedKVCache,
     block_row: jnp.ndarray,  # (pages_per_slot,) — ONE slot's pages
-    k_seq: jnp.ndarray,  # (S, H_kv, D) — prefilled keys, rows < n_valid real
+    k_seq: jnp.ndarray,  # (..., S, H_kv, D) — prefilled keys, rows < n_valid real
     v_seq: jnp.ndarray,
     n_valid: jnp.ndarray,
 ) -> PagedKVCache:
@@ -182,16 +187,35 @@ def paged_write(
 
     Rows ≥ ``n_valid`` (prompt-bucket padding) are redirected to the null
     page instead of being masked out, so the scatter shape is static.
+    A stack of arenas (leading layer axes on ``cache`` and on the
+    sequences alike) is written in one scatter.
     """
-    P = cache.k.shape[1]
-    S = k_seq.shape[0]
+    P = cache.k.shape[-2]
+    S = k_seq.shape[-3]
     pos = jnp.arange(S)
     page = jnp.where(pos < n_valid, block_row[pos // P], NULL_PAGE)
-    off = pos % P
-    return PagedKVCache(
-        k=cache.k.at[page, off].set(k_seq.astype(cache.k.dtype)),
-        v=cache.v.at[page, off].set(v_seq.astype(cache.v.dtype)),
-    )
+    return _scatter_rows(cache, page, pos % P, k_seq, v_seq)
+
+
+def _scatter_rows(cache: PagedKVCache, page, off, k_new, v_new) -> PagedKVCache:
+    """Write token t's ``(H_kv, D)`` keys and values at row ``off[t]`` of
+    page ``page[t]``, in every arena of a stack (``cache`` leaves
+    ``(..., n_pages, H_kv, P, D)``, new values ``(..., T, H_kv, D)``).
+    The stack is scattered as its ``(rows, D)`` matrix of ``D``-wide rows,
+    which keeps the arena's own layout: a scatter of ``(H_kv, D)``
+    windows, or one batched over the stack, has XLA lay the whole arena
+    out anew around it."""
+    *lead, n_pages, Hkv, P, D = cache.k.shape
+    first_page = jnp.arange(math.prod(lead), dtype=page.dtype)[:, None, None] * n_pages
+    rows = (((first_page + page[:, None]) * Hkv + jnp.arange(Hkv)) * P
+            + off[:, None]).reshape(-1)
+
+    def put(stack, new):
+        flat = stack.reshape(-1, D)
+        flat = flat.at[rows].set(new.reshape(-1, D).astype(stack.dtype))
+        return flat.reshape(stack.shape)
+
+    return PagedKVCache(k=put(cache.k, k_new), v=put(cache.v, v_new))
 
 
 class PageAllocator:

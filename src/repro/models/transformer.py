@@ -584,11 +584,10 @@ def paged_insert_prompt(paged, dense, block_row, n_valid):
     """
 
     def insert_one(pg, dn):
-        def per_rep(pk, pv, dk, dv):
-            return cache_lib.paged_write(
-                cache_lib.PagedKVCache(k=pk, v=pv), block_row,
-                dk[0], dv[0], n_valid,
-            )
-        return jax.vmap(per_rep)(pg.k, pg.v, dn.k, dn.v)
+        # the segment's stacked arenas in one scatter: dn.k is
+        # (repeats, 1, S, H_kv, D), pg.k (repeats, n_pages, H_kv, P, D)
+        return cache_lib.paged_write(
+            pg, block_row, dn.k[:, 0], dn.v[:, 0], n_valid
+        )
 
     return jax.tree.map(insert_one, paged, dense, is_leaf=_is_paged)

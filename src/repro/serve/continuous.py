@@ -24,10 +24,11 @@ comes from:
   point at the null page, so in-flight garbage writes stay invisible.
 
 Attention on the hot path runs through ``kernels/decode_attention``
-(``use_kernel="auto"``: the Pallas kernel on TPU, its bit-equal jitted
-XLA reference elsewhere), with the choice reported in ``kernel_plan``
-and per-token hit counts in ``kernel_hits`` — the serve-side analogue of
-``wire_kernel_hits``.
+(``use_kernel="auto"``: the paged Pallas kernel on TPU, which reads each
+slot's live pages through the block table; elsewhere the jitted XLA
+reference over the gathered page view), with the choice reported in
+``kernel_plan`` and per-token hit counts in ``kernel_hits`` — the
+serve-side analogue of ``wire_kernel_hits``.
 
 Requests resolve through the same ``Ticket`` handle the batcher uses; an
 evicted or errored request **fails its ticket immediately** instead of
@@ -203,9 +204,9 @@ class ContinuousLMEngine:
       n_pages: arena capacity; default fully provisions
         ``n_slots × max_seq`` (+ the null page).  Smaller values
         oversubscribe — admission control queues what doesn't fit.
-      use_kernel: decode-attention path — True forces the Pallas kernel,
-        False the jitted XLA reference, "auto" picks by backend; the
-        decision is reported in ``kernel_plan``.
+      use_kernel: decode-attention path — True forces the paged Pallas
+        kernel, False the jitted XLA reference, "auto" picks by backend;
+        the decision is reported in ``kernel_plan``.
       temperature / seed: sampling knobs (0 → greedy argmax).
       metrics / tracer / tag: same observability surfaces as
         ``ServeEngine`` (``RunReport.from_serve`` accepts either).
@@ -234,7 +235,8 @@ class ContinuousLMEngine:
         self.seed = seed
         self.metrics = metrics if metrics is not None else ServeMetrics()
         self.tracer = tracer if tracer is not None else _trace.current_tracer()
-        self.kernel_plan = decode_kernel_plan(cfg, use_kernel=use_kernel)
+        self.kernel_plan = decode_kernel_plan(cfg, use_kernel=use_kernel,
+                                              paged=True)
         self._impl = resolve_decode_attn(
             use_kernel, sliding_window=cfg.sliding_window
         )
@@ -437,10 +439,16 @@ class ContinuousLMEngine:
             if not active:
                 return 0
             n_slots = self.sched.n_slots
+            P = self.sched.page_size
             t0 = time.perf_counter()
             try:
-                with self._span("serve/decode_step", active=len(active),
-                                slots=n_slots):
+                # pages the paged kernel reads (each slot's live pages,
+                # the new token's included) against the block table's
+                with self._span(
+                    "serve/decode_step", active=len(active), slots=n_slots,
+                    kv_pages_read=int(np.sum((self.sched.length + P) // P)),
+                    kv_pages_table=self.sched.block.size,
+                ):
                     with self._span("serve/decode_step.inputs"):
                         nxt, self._cache = self._step(
                             self.params,

@@ -32,8 +32,8 @@ def kernels_forced(monkeypatch):
     )
     monkeypatch.setattr(
         continuous, "decode_kernel_plan",
-        lambda cfg, use_kernel="auto": attention.decode_kernel_plan(
-            cfg, use_kernel=True
+        lambda cfg, use_kernel="auto", **kw: attention.decode_kernel_plan(
+            cfg, use_kernel=True, **kw
         ),
     )
     monkeypatch.setattr(wire._FusedWire, "_kernel_active", lambda self: True)

@@ -6,9 +6,10 @@ The contracts this file pins down:
   reuse, page 0 never handed out.
 * paged cache ops — write/append/view round-trip exactly; null-page
   redirection keeps inactive slots invisible.
-* decode-attention hot path — the Pallas kernel (interpret on CPU,
-  single KV block) is BITWISE equal to the jitted XLA reference, through
-  ``attn_apply`` and standalone.
+* decode-attention hot path — the dense Pallas kernel (interpret on
+  CPU, single KV block) is BITWISE equal to the jitted XLA reference,
+  through ``attn_apply`` and standalone; the paged kernel serves the
+  same greedy tokens as the XLA path over the gathered page view.
 * continuous ≡ one-at-a-time ≡ dense-baseline decode (greedy ids — the
   slot scheduler may not change a single served token).
 * retrace freedom — the ONE compiled step's jit cache stays at size 1
@@ -133,6 +134,45 @@ class TestPagedCacheOps:
         np.testing.assert_array_equal(k[0, 5], k_tok[0])
         np.testing.assert_array_equal(k[1, 0], k_tok[1])
         np.testing.assert_array_equal(k[0, :5], k_seq[:5])  # intact
+
+    def test_arena_layout_matches_dense_reference(self):
+        """Writes and appends land at ``arena[page, head, row]`` — the
+        (n_pages, H_kv, page_size, D) layout the paged kernel copies —
+        where a dense per-slot cache holds ``cache[slot, pos, head]``."""
+        P, Hkv, D, pps = 4, 2, 3, 3
+        rng = np.random.default_rng(4)
+        cache = cache_lib.paged_kv_cache_init(1 + 2 * pps, P, Hkv, D, jnp.float32)
+        assert cache.k.shape == (1 + 2 * pps, Hkv, P, D)
+        block = np.asarray([[5, 2, 6], [1, 4, 3]], np.int32)
+        dense_k = np.zeros((2, pps * P, Hkv, D), np.float32)
+        dense_v = np.zeros_like(dense_k)
+        fill = [6, 3]
+        for s, n in enumerate(fill):
+            kv = rng.normal(size=(2, 8, Hkv, D)).astype(np.float32)
+            cache = cache_lib.paged_write(cache, jnp.asarray(block[s]),
+                                          jnp.asarray(kv[0]), jnp.asarray(kv[1]), n)
+            dense_k[s, :n], dense_v[s, :n] = kv[0, :n], kv[1, :n]
+        length = np.asarray(fill, np.int32)
+        for _ in range(4):  # crosses page boundaries in both slots
+            tok = rng.normal(size=(2, 2, Hkv, D)).astype(np.float32)
+            cache = cache_lib.paged_append(cache, jnp.asarray(block),
+                                           jnp.asarray(length),
+                                           jnp.asarray(tok[0]), jnp.asarray(tok[1]))
+            for s in range(2):
+                dense_k[s, length[s]], dense_v[s, length[s]] = tok[0, s], tok[1, s]
+            length += 1
+        k, v = np.asarray(cache.k), np.asarray(cache.v)
+        for s in range(2):
+            for pos in range(length[s]):
+                page, row = block[s, pos // P], pos % P
+                np.testing.assert_array_equal(k[page, :, row], dense_k[s, pos])
+                np.testing.assert_array_equal(v[page, :, row], dense_v[s, pos])
+        view_k, view_v = cache_lib.paged_view(cache, jnp.asarray(block))
+        for s in range(2):
+            np.testing.assert_array_equal(np.asarray(view_k[s, :length[s]]),
+                                          dense_k[s, :length[s]])
+            np.testing.assert_array_equal(np.asarray(view_v[s, :length[s]]),
+                                          dense_v[s, :length[s]])
 
     def test_null_page_swallows_inactive_writes(self):
         P, Hkv, D = 2, 1, 2
@@ -309,9 +349,9 @@ class TestContinuousEquivalence:
             assert t.result().tolist() == np.asarray(dense)[0].tolist()
 
     def test_forced_pallas_kernel_on_hot_path(self, tiny_lm):
-        """use_kernel=True routes the compiled step through the Pallas
-        kernel (interpret on CPU) and counts hits — and the served ids
-        are identical to the XLA-reference path (bit-equal contract)."""
+        """use_kernel=True routes the compiled step through the paged
+        Pallas kernel (interpret on CPU) and counts hits — and the served
+        ids are identical to the XLA-reference path."""
         cfg, params = tiny_lm
         reqs = self._requests(cfg)[:3]
         outs = {}
@@ -325,9 +365,44 @@ class TestContinuousEquivalence:
             outs[use_kernel] = [t.result().tolist() for t in tickets]
             impl = "pallas" if use_kernel else "xla"
             assert eng.kernel_plan["path"] == impl
+            assert eng.kernel_plan["kernel"] == (
+                "paged_decode_attention" if use_kernel
+                else "paged_view + decode_attention_xla")
             assert eng.kernel_hits[impl] > 0
             other = "xla" if use_kernel else "pallas"
             assert eng.kernel_hits[other] == 0
+        assert outs[True] == outs[False]
+
+    def test_paged_kernel_serves_xla_tokens_through_churn(self, tiny_lm):
+        """The paged kernel and the XLA path serve the same greedy tokens
+        through joins, retirements and page reuse: 9 requests over 3
+        slots on an arena with pages for two full-length requests, so
+        freed pages go to later requests in another order."""
+        cfg, params = tiny_lm
+        rng = np.random.default_rng(9)
+        reqs = [(rng.integers(0, cfg.vocab_size, size=int(n)).astype(np.int32),
+                 int(g))
+                for n, g in zip(rng.integers(1, 10, size=9),
+                                rng.integers(2, 11, size=9))]
+        outs, reused = {}, {}
+        for use_kernel in (True, False):
+            eng = ContinuousLMEngine(cfg, params, n_slots=3, page_size=4,
+                                     max_seq=20, n_pages=11,
+                                     use_kernel=use_kernel)
+            owner = {}  # page -> the last request that held it
+            reused[use_kernel] = 0
+            tickets = [eng.submit(p, max_new=g) for p, g in reqs]
+            while eng.sched.backlog or eng.sched.n_active:
+                eng.step()
+                for r in eng.sched.slots:
+                    if r is None or owner.get(r.pages[0]) == r.rid:
+                        continue
+                    reused[use_kernel] += any(p in owner for p in r.pages)
+                    owner.update((p, r.rid) for p in r.pages)
+            outs[use_kernel] = [t.result().tolist() for t in tickets]
+            assert eng.compiled_step_cache_size == 1
+        # joins that were placed on pages an earlier request had written
+        assert reused[True] == reused[False] >= 4
         assert outs[True] == outs[False]
 
     def test_temperature_sampling_is_occupancy_invariant(self, tiny_lm):
@@ -557,8 +632,10 @@ class TestContinuousObservability:
         assert sorted(s["tags"]["rid"] for s in prefill) == [0, 1, 2]
 
     def test_decode_step_names_its_device_work(self):
-        """The compiled decode step's operations carry the page gather,
-        the token's append and the weight casts in their op metadata."""
+        """The compiled decode step's operations carry the token's append
+        and the weight casts in their op metadata, and the page gather
+        on the XLA path only: the paged kernel reads the arena through
+        the block table."""
         from repro.serve import continuous
 
         cfg = _tiny_cfg(compute_dtype="bfloat16")
@@ -571,8 +648,9 @@ class TestContinuousObservability:
             step = continuous._build_step(cfg, impl, 0.0, False)
             text = step.lower(params, i32(n_slots, 1), cache, i32(n_slots, pps),
                               i32(n_slots), i32(n_slots)).compile().as_text()
-            for scope in ("kv_gather", "kv_append", "weight_cast"):
+            for scope in ("kv_append", "weight_cast"):
                 assert f"/{scope}/" in text, (impl, scope)
+            assert ("/kv_gather/" in text) == (impl == "xla"), impl
 
     def test_ledger_coalesces_inference_events(self, tiny_lm):
         cfg, params = tiny_lm

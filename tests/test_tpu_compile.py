@@ -16,6 +16,8 @@ imports every test file.
 from __future__ import annotations
 
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +77,21 @@ def test_decode_attention_at_qwen2_widths(one_chip):
     assert _has_kernel(c)
 
 
+def test_paged_decode_attention_at_qwen2_widths(one_chip):
+    # the decode-heavy cell's arena: 32 slots of 144 pages of 16, bf16
+    n_slots, Hq, Hkv, D, P, pps = 32, 12, 2, 128, 16, 144
+    n_pages = 1 + n_slots * pps
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    arena = sds((n_pages, Hkv, P, D), jnp.bfloat16)
+    c = _compile(
+        lambda q, k, v, b, n: da_ops.paged_decode_attention(
+            q, k, v, b, n, interpret=False),
+        sds((n_slots, Hq, D), jnp.bfloat16), arena, arena,
+        sds((n_slots, pps), jnp.int32), sds((n_slots,), jnp.int32),
+    )
+    assert _has_kernel(c)
+
+
 def test_flash_attention_at_prefill_widths(one_chip):
     B, T, H, D = 1, 2048, 12, 128
     x = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=one_chip)
@@ -123,10 +140,29 @@ def test_int8_quant_dequant(one_chip):
     assert _has_kernel(c)
 
 
+def _roofline_kernel_pattern() -> str:
+    """The kernel-name pattern of the benchmark's decode-kernel reader."""
+    src = (Path(__file__).resolve().parents[1] / "chipbench" / "metrics"
+           / "decode_attention_roofline.py").read_text()
+    return re.search(r'^KERNEL = r"(.+)"$', src, re.M).group(1)
+
+
+def _gathers_from(hlo: str, dim: int) -> tuple:
+    """(every gather, the gathers whose operand has a ``dim``-long axis)
+    of an optimized HLO module, over all its computations."""
+    dims = {}
+    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]", hlo, re.M):
+        dims[m.group(1)] = [int(d) for d in m.group(2).split(",") if d]
+    gathers = re.findall(r"^\s*(?:ROOT )?%(\S+) = \S+ gather\(%([^,)]+)", hlo, re.M)
+    return gathers, [g for g, operand in gathers if dim in dims[operand]]
+
+
 def test_paged_decode_step_qwen2_fits_one_chip(one_chip, monkeypatch):
     """The continuous engine's one compiled step at qwen2-1.5b's
-    published widths and depth: it takes the Pallas decode kernel and
-    its arguments plus temporaries fit one v5e chip."""
+    published widths and depth: it takes the paged Pallas decode kernel,
+    under a name the benchmark's roofline reader matches; no gather
+    reads the page arena; and its arguments plus temporaries fit one
+    v5e chip."""
     from repro.configs import get_config
     from repro.models import transformer as tf
     from repro.serve import continuous
@@ -155,6 +191,13 @@ def test_paged_decode_step_qwen2_fits_one_chip(one_chip, monkeypatch):
         params, i32(n_slots, 1), cache, i32(n_slots, pps), i32(n_slots),
         i32(n_slots),
     ).compile()
-    assert _has_kernel(c)
+    hlo = c.as_text()
+    kernels = [m.group(1) for m in re.finditer(
+        r"^\s*%(\S+) = .*custom_call_target=\"tpu_custom_call\"", hlo, re.M)]
+    assert kernels and all(k.startswith("paged_decode_attention") for k in kernels)
+    assert all(re.search(_roofline_kernel_pattern(), k) for k in kernels)
+    gathers, from_arena = _gathers_from(hlo, n_pages)
+    assert gathers  # the embedding row and the block-table lookup
+    assert not from_arena
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
