@@ -1,5 +1,6 @@
 """Jit'd wrappers for the decode-attention kernels: the dense one over a
-per-slot cache, and the paged one over a page arena and its block table."""
+per-slot cache, the paged one over a page arena and its block table, and
+the paged MLA one over a latent arena."""
 
 from __future__ import annotations
 
@@ -121,3 +122,54 @@ def decode_attention_xla(
     denom = jnp.where(l > 0.0, l, 1.0)
     out = acc / denom[..., None]
     return out.reshape(B, Hq, D).astype(q.dtype)
+
+
+@partial(jax.jit, static_argnames=("value_width", "scale", "pages_per_chunk", "interpret"))
+def paged_mla_decode(
+    q: jnp.ndarray,  # (n_slots, H, W): [q_nope·W_uk ‖ q_pe] per head
+    kv_pages: jnp.ndarray,  # (n_pages, P, W) — the latent arena
+    block: jnp.ndarray,  # (n_slots, pages_per_slot) physical page ids
+    valid_len: jnp.ndarray,  # (n_slots,) valid positions, each ≥ 1
+    *,
+    value_width: int,
+    scale: float,
+    pages_per_chunk: int = 8,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Absorbed MLA decode for every slot, read through the block table:
+    scores against whole latent rows, values their first ``value_width``
+    columns; (n_slots, H, value_width) f32.  Same result as
+    ``paged_mla_decode_xla`` over ``paged_mla_view`` of the arena, up to
+    the rounding of the weights to the arena's dtype and the order of
+    the f32 sums."""
+    from repro.kernels.decode_attention.paged_mla_kernel import paged_mla_decode_fwd
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return paged_mla_decode_fwd(
+        q.astype(kv_pages.dtype), kv_pages, block, valid_len, value_width=value_width,
+        scale=scale, pages_per_chunk=pages_per_chunk, interpret=interpret,
+    )
+
+
+@partial(jax.jit, static_argnames=("value_width", "scale"))
+def paged_mla_decode_xla(
+    q: jnp.ndarray,  # (B, H, W)
+    kv: jnp.ndarray,  # (B, S, W) — a dense per-slot view of the arena
+    valid_len: jnp.ndarray,  # (B,)
+    *,
+    value_width: int,
+    scale: float,
+) -> jnp.ndarray:
+    """The kernel's XLA reference and the CPU path: the same absorbed
+    attention in f32 over a dense view; (B, H, value_width) f32."""
+    S = kv.shape[1]
+    kv = kv.astype(jnp.float32)
+    s = jnp.einsum("bhw,bsw->bhs", q.astype(jnp.float32), kv,
+                   preferred_element_type=jnp.float32) * scale
+    mask = (jnp.arange(S)[None, :] < valid_len.reshape(-1, 1))[:, None, :]
+    s = jnp.where(mask, s, NEG_INF)
+    p = jnp.where(mask, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    acc = jnp.einsum("bhs,bsr->bhr", p, kv[..., :value_width],
+                     preferred_element_type=jnp.float32)
+    return acc / jnp.sum(p, axis=-1, keepdims=True)

@@ -35,6 +35,91 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.decode_attention.kernel import NEG_INF
 
 
+class SlotPages:
+    """Double-buffered copies of one slot's live pages, ``pages_per_chunk``
+    a chunk, from page arenas left in HBM into VMEM buffers; shared by the
+    GQA and the MLA paged kernels.
+
+    ``streams`` pairs each arena with its buffer and a flag: page ``i`` of
+    a chunk lands at rows ``[i·P, (i+1)·P)`` of the buffer's position axis
+    (the second to last), and a flagged buffer has the rows of the chunk's
+    pages past the slot's last live page zeroed instead of copied, so that
+    masked weights (exact zeros) multiply finite values.  Stream ``j``
+    waits on semaphore row ``j`` of ``sems`` ((n_streams, 2)).
+    """
+
+    def __init__(self, block_ref, slot, valid, streams, sems, *,
+                 pages_per_slot: int, pages_per_chunk: int, page_size: int):
+        self.block_ref = block_ref
+        self.streams = streams
+        self.sems = sems
+        self.C = pages_per_chunk
+        self.P = page_size
+        self.n_live = (valid + page_size - 1) // page_size
+        self.n_chunks = (self.n_live + self.C - 1) // self.C
+        self.row0 = slot * pages_per_slot
+
+    def _rows(self, i):
+        return pl.ds(pl.multiple_of(i * self.P, self.P), self.P)
+
+    @staticmethod
+    def _at(buf, b, rows):
+        return buf.at[b, :, rows, :] if len(buf.shape) == 4 else buf.at[b, rows, :]
+
+    def _each_copy(self, chunk, b, i, act):
+        """``act`` on the copy of page ``i`` of ``chunk`` into buffer ``b``,
+        stream by stream."""
+        page = self.block_ref[self.row0 + chunk * self.C + i]
+        rows = self._rows(i)
+        for j, (arena, buf, _) in enumerate(self.streams):
+            act(pltpu.make_async_copy(arena.at[page], self._at(buf, b, rows), self.sems.at[j, b]))
+
+    def live_in(self, chunk):
+        return jnp.minimum(self.C, self.n_live - chunk * self.C)
+
+    def start(self, chunk, b):
+        """Issue the copies of ``chunk`` into buffer ``b``."""
+        def copy(i, carry):
+            self._each_copy(chunk, b, i, lambda c: c.start())
+            return carry
+
+        def zero(i, carry):
+            rows = self._rows(i)
+            for _, buf, flag in self.streams:
+                if flag:
+                    dst = self._at(buf, b, rows)
+                    dst[...] = jnp.zeros(dst.shape, buf.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, self.live_in(chunk), copy, 0)
+        jax.lax.fori_loop(self.live_in(chunk), self.C, zero, 0)
+
+    def wait(self, chunk, b):
+        def one(i, carry):
+            self._each_copy(chunk, b, i, lambda c: c.wait())
+            return carry
+
+        jax.lax.fori_loop(0, self.live_in(chunk), one, 0)
+
+    def loop(self, compute):
+        """Run ``compute(chunk, b)`` over the slot's chunks, the copies of
+        chunk c + 1 in flight while chunk c is computed."""
+        self.start(0, 0)
+
+        def body(chunk, carry):
+            b = chunk % 2
+
+            @pl.when(chunk + 1 < self.n_chunks)
+            def _prefetch():
+                self.start(chunk + 1, 1 - b)
+
+            self.wait(chunk, b)
+            compute(chunk, b)
+            return carry
+
+        jax.lax.fori_loop(0, self.n_chunks, body, 0)
+
+
 def _paged_decode_kernel(
     block_ref,  # SMEM (n_slots · pages_per_slot,) physical page ids
     valid_ref,  # SMEM (n_slots,) valid positions per slot
@@ -56,60 +141,14 @@ def _paged_decode_kernel(
     Hkv, P = k_arena.shape[1], k_arena.shape[2]
     C = pages_per_chunk
     valid = valid_ref[slot]
-    n_live = (valid + P - 1) // P
-    n_chunks = (n_live + C - 1) // C
-    row0 = slot * pages_per_slot
-
-    def page_copies(chunk, buf, i):
-        page = block_ref[row0 + chunk * C + i]
-        rows = pl.ds(pl.multiple_of(i * P, P), P)
-        return (
-            pltpu.make_async_copy(k_arena.at[page], k_buf.at[buf, :, rows, :],
-                                  sems.at[0, buf]),
-            pltpu.make_async_copy(v_arena.at[page], v_buf.at[buf, :, rows, :],
-                                  sems.at[1, buf]),
-        )
-
-    def live_in(chunk):
-        return jnp.minimum(C, n_live - chunk * C)
-
-    def start(chunk, buf):
-        def copy(i, carry):
-            k_copy, v_copy = page_copies(chunk, buf, i)
-            k_copy.start()
-            v_copy.start()
-            return carry
-
-        def zero(i, carry):
-            rows = pl.ds(pl.multiple_of(i * P, P), P)
-            v_buf[buf, :, rows, :] = jnp.zeros((Hkv, P, v_buf.shape[-1]), v_buf.dtype)
-            return carry
-
-        jax.lax.fori_loop(0, live_in(chunk), copy, 0)
-        jax.lax.fori_loop(live_in(chunk), C, zero, 0)
-
-    def wait(chunk, buf):
-        def one(i, carry):
-            k_copy, v_copy = page_copies(chunk, buf, i)
-            k_copy.wait()
-            v_copy.wait()
-            return carry
-
-        jax.lax.fori_loop(0, live_in(chunk), one, 0)
+    pages = SlotPages(block_ref, slot, valid, [(k_arena, k_buf, False), (v_arena, v_buf, True)],
+                      sems, pages_per_slot=pages_per_slot, pages_per_chunk=C, page_size=P)
 
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    start(0, 0)
 
-    def body(chunk, carry):
-        buf = chunk % 2
-
-        @pl.when(chunk + 1 < n_chunks)
-        def _prefetch():
-            start(chunk + 1, 1 - buf)
-
-        wait(chunk, buf)
+    def compute(chunk, buf):
         for h in range(Hkv):
             q = q_ref[0, h].astype(jnp.float32)  # (G, D)
             k = k_buf[buf, h].astype(jnp.float32)  # (C·P, D)
@@ -136,9 +175,8 @@ def _paged_decode_kernel(
             )
             m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
             l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
-        return carry
 
-    jax.lax.fori_loop(0, n_chunks, body, 0)
+    pages.loop(compute)
 
     for h in range(Hkv):
         l = l_ref[h][:, 0:1]

@@ -26,19 +26,25 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, widths)
 
 
-@partial(jax.jit, static_argnames=("causal", "window", "q_offset", "bq", "bk", "interpret"))
+@partial(jax.jit, static_argnames=("causal", "window", "q_offset", "scale", "f32_products",
+                                   "bq", "bk", "interpret"))
 def flash_attention(
     q: jnp.ndarray,  # (B, T, Hq, D) — model layout
     k: jnp.ndarray,  # (B, S, Hkv, D)
-    v: jnp.ndarray,
+    v: jnp.ndarray,  # (B, S, Hkv, Dv)
     *,
     causal: bool = True,
     window: int = 0,
     q_offset: int = 0,
+    scale: float | None = None,
+    f32_products: bool = True,
     bq: int = 128,
     bk: int = 128,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
+    """(B, T, Hq, Dv); ``scale`` defaults to D^-0.5.  ``f32_products``
+    upcasts the products' inputs to f32; off, the MXU takes the operands'
+    dtype (the softmax weights rounded to V's), accumulating in f32."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     B, T, Hq, D = q.shape
@@ -57,6 +63,8 @@ def flash_attention(
         causal=causal,
         window=window,
         q_offset=q_offset,
+        scale=D ** -0.5 if scale is None else scale,
+        f32_products=f32_products,
         bq=bq_eff,
         bk=bk_eff,
         interpret=interpret,

@@ -97,6 +97,9 @@ _DECODE_KERNEL = {
     ("xla", False): "decode_attention_xla",
     ("xla", True): "paged_view + decode_attention_xla",
 }
+#: the same over MLA's paged latent arena
+_MLA_DECODE_KERNEL = {"pallas": "paged_mla_decode",
+                      "xla": "paged_mla_view + paged_mla_decode_xla"}
 
 
 def decode_kernel_plan(cfg: ModelConfig, *, use_kernel="auto",
@@ -125,7 +128,9 @@ def decode_kernel_plan(cfg: ModelConfig, *, use_kernel="auto",
             "(equal to the paged kernel up to f32 summation order)" if paged
             else "(bit-equal to the kernel)"
         )
-    return {"path": path, "kernel": _DECODE_KERNEL[path, paged],
+    kernel = (_MLA_DECODE_KERNEL[path] if paged and cfg.mixer == "mla"
+              else _DECODE_KERNEL[path, paged])
+    return {"path": path, "kernel": kernel,
             "reason": reason, "backend": backend}
 
 

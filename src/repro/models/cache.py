@@ -21,6 +21,12 @@ rows are whole TPU tiles, so the paged decode kernel
 (``kernels/decode_attention/paged_kernel.py``) copies a slot's live pages
 straight from the arena.  ``paged_view`` gathers a dense per-slot view for
 the XLA decode path.
+
+MLA's serving arena, ``PagedMLACache``, is laid out ``(n_pages,
+page_size, W)``: one row per position, the normed latent and the roped
+shared key side by side (padded to whole lane tiles), so the paged MLA kernel
+(``kernels/decode_attention/paged_mla_kernel.py``) copies one block per
+page for both its keys and its values.
 """
 
 from __future__ import annotations
@@ -197,25 +203,104 @@ def paged_write(
     return _scatter_rows(cache, page, pos % P, k_seq, v_seq)
 
 
+def _arena_rows(lead: tuple, n_pages: int, heads: int, P: int, page, off):
+    """Row numbers, in an arena stack seen as its ``(rows, D)`` matrix,
+    of token t's ``heads`` rows at row ``off[t]`` of page ``page[t]``,
+    for every arena of the stack (leading axes ``lead``)."""
+    first_page = jnp.arange(math.prod(lead), dtype=page.dtype)[:, None, None] * n_pages
+    return (((first_page + page[:, None]) * heads + jnp.arange(heads)) * P
+            + off[:, None]).reshape(-1)
+
+
+def _put_rows(stack, rows, new):
+    """Scatter ``new`` as ``D``-wide rows into ``stack``'s ``(rows, D)``
+    matrix, which keeps the arena's own layout: a scatter of windows, or
+    one batched over the stack, has XLA lay the whole arena out anew
+    around it."""
+    D = stack.shape[-1]
+    flat = stack.reshape(-1, D)
+    flat = flat.at[rows].set(new.reshape(-1, D).astype(stack.dtype))
+    return flat.reshape(stack.shape)
+
+
 def _scatter_rows(cache: PagedKVCache, page, off, k_new, v_new) -> PagedKVCache:
     """Write token t's ``(H_kv, D)`` keys and values at row ``off[t]`` of
     page ``page[t]``, in every arena of a stack (``cache`` leaves
-    ``(..., n_pages, H_kv, P, D)``, new values ``(..., T, H_kv, D)``).
-    The stack is scattered as its ``(rows, D)`` matrix of ``D``-wide rows,
-    which keeps the arena's own layout: a scatter of ``(H_kv, D)``
-    windows, or one batched over the stack, has XLA lay the whole arena
-    out anew around it."""
-    *lead, n_pages, Hkv, P, D = cache.k.shape
-    first_page = jnp.arange(math.prod(lead), dtype=page.dtype)[:, None, None] * n_pages
-    rows = (((first_page + page[:, None]) * Hkv + jnp.arange(Hkv)) * P
-            + off[:, None]).reshape(-1)
+    ``(..., n_pages, H_kv, P, D)``, new values ``(..., T, H_kv, D)``)."""
+    *lead, n_pages, Hkv, P, _ = cache.k.shape
+    rows = _arena_rows(lead, n_pages, Hkv, P, page, off)
+    return PagedKVCache(k=_put_rows(cache.k, rows, k_new), v=_put_rows(cache.v, rows, v_new))
 
-    def put(stack, new):
-        flat = stack.reshape(-1, D)
-        flat = flat.at[rows].set(new.reshape(-1, D).astype(stack.dtype))
-        return flat.reshape(stack.shape)
 
-    return PagedKVCache(k=put(cache.k, k_new), v=put(cache.v, v_new))
+# ----------------------------------------------------------------------------
+# Paged latent cache — MLA's serving arena
+# ----------------------------------------------------------------------------
+
+
+class PagedMLACache(NamedTuple):
+    """Physical page arena of MLA's latent cache for one layer.
+
+    Each position holds one row: the normed latent ``c_kv``
+    (kv_lora_rank values), then the roped shared key ``k_pe``
+    (qk_rope_head_dim), then zeros up to a whole number of 128-lane
+    tiles (``latent_width``: 576 values in 640 lanes for DeepSeek-V3, the
+    lanes a 576-wide row takes in the chip's tiled layout anyway, made
+    explicit so a page is one aligned copy).  The absorbed decode reads
+    the whole row as the key and its first ``kv_lora_rank`` values as the
+    value, so one copy of a page feeds both.  Addressed through the same
+    block table and ``PageAllocator`` as ``PagedKVCache``.
+    """
+
+    kv: jnp.ndarray  # (n_pages, page_size, latent_width)
+
+
+def latent_width(kv_lora_rank: int, rope_dim: int) -> int:
+    """Lanes of one latent row: kv_lora_rank + rope_dim, padded to 128s."""
+    return -(-(kv_lora_rank + rope_dim) // 128) * 128
+
+
+def pad_lanes(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """Zeros appended to the last axis up to ``width``."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def paged_mla_cache_init(n_pages: int, page_size: int, width: int, dtype) -> PagedMLACache:
+    return PagedMLACache(kv=jnp.zeros((n_pages, page_size, width), dtype))
+
+
+def paged_mla_append(cache: PagedMLACache, block, length, row) -> PagedMLACache:
+    """Scatter one latent row per slot (``row``: (n_slots, r + dr)) at its
+    next logical position; inactive slots write into the null page."""
+    P = cache.kv.shape[-2]
+    with jax.named_scope("latent_append"):
+        page = jnp.take_along_axis(block, (length // P)[:, None], axis=1)[:, 0]
+        return _scatter_mla(cache, page, length % P, row)
+
+
+def paged_mla_write(cache: PagedMLACache, block_row, rows, n_valid) -> PagedMLACache:
+    """Write a prefilled sequence's latent rows (``(..., S, r + dr)``, rows
+    ≥ ``n_valid`` redirected to the null page) into one slot's pages,
+    every arena of a stack in one scatter."""
+    P = cache.kv.shape[-2]
+    S = rows.shape[-2]
+    pos = jnp.arange(S)
+    page = jnp.where(pos < n_valid, block_row[pos // P], NULL_PAGE)
+    return _scatter_mla(cache, page, pos % P, rows)
+
+
+def _scatter_mla(cache: PagedMLACache, page, off, new) -> PagedMLACache:
+    *lead, n_pages, P, W = cache.kv.shape
+    rows = _arena_rows(lead, n_pages, 1, P, page, off)
+    return PagedMLACache(kv=_put_rows(cache.kv, rows, pad_lanes(new, W)))
+
+
+def paged_mla_view(cache: PagedMLACache, block: jnp.ndarray) -> jnp.ndarray:
+    """Each slot's pages gathered into a dense (n_slots, pages_per_slot ·
+    P, W) view: the XLA decode path and the kernel's reference."""
+    n_slots, pp = block.shape
+    _, P, W = cache.kv.shape
+    with jax.named_scope("kv_gather"):
+        return jnp.take(cache.kv, block.reshape(-1), axis=0).reshape(n_slots, pp * P, W)
 
 
 class PageAllocator:

@@ -39,6 +39,20 @@ class MoEConfig:
     # "after_first_k" (dense for the first `first_k_dense` layers)
     layer_mode: str = "all"
     first_k_dense: int = 0
+    # routing: "softmax" (top-k of the softmax, renormalised) or
+    # "sigmoid" (DeepSeek-V3's noaux_tc: top-k of sigmoid scores plus a
+    # selection-only bias, limited to the ``topk_group`` best of
+    # ``n_group`` groups, gates normalised and scaled by ``routed_scaling``)
+    scoring: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    # the routed experts this chip holds (expert parallelism): the router
+    # keeps all ``num_experts`` outputs, the layer computes only these
+    # experts' part of the result, for the tokens routed to them, with no
+    # capacity and no drops.  Empty: the capacity dispatch over all
+    # experts (the training path).
+    held_experts: tuple = ()
 
 
 @dataclass(frozen=True)
@@ -77,6 +91,15 @@ class ModelConfig:
     # attention details
     mixer: Mixer = "attn"  # default mixer for attention-family layers
     rope_theta: float = 10000.0
+    # YaRN context extension of the rotary frequencies (DeepSeek-V3's
+    # rope_scaling): 0 = plain RoPE; else the scaling factor, the
+    # correction betas, the pre-training context and the ``mscale_all_dim``
+    # that sets the softmax's mscale² factor
+    yarn_factor: float = 0.0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_original_max_pos: int = 4096
+    yarn_mscale_all_dim: float = 1.0
     qkv_bias: bool = False
     sliding_window: int = 0  # 0 → full causal attention
     mrope_sections: tuple = ()  # e.g. (16, 24, 24) → M-RoPE (qwen2-vl)
@@ -131,6 +154,13 @@ class ModelConfig:
             raise ValueError("num_layers must be a multiple of the hybrid pattern")
 
     # ---- derived ----
+    @property
+    def holds_expert_share(self) -> bool:
+        """The expert layers hold a share of the routed experts
+        (``MoEConfig.held_experts``), and the forward can count each
+        token's routes to them."""
+        return self.moe is not None and bool(self.moe.held_experts)
+
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads

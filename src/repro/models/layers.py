@@ -7,6 +7,8 @@ leaf *names* drive the sharding rules in ``repro.sharding.rules``.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -141,10 +143,38 @@ def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
     )
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotate ``x`` (..., T, H, D) by per-token ``positions`` (..., T)."""
+def yarn_frequencies(head_dim: int, theta: float, factor: float, beta_fast: float,
+                     beta_slow: float, original_max_pos: int) -> jnp.ndarray:
+    """(head_dim/2,) YaRN inverse frequencies (arXiv:2309.00071, as
+    DeepSeek-V3 uses them): the plain frequencies where a dimension turns
+    more than ``beta_fast`` times over the pre-training context, those
+    divided by ``factor`` where it turns fewer than ``beta_slow`` times,
+    and a linear ramp between the two dimensions."""
+    def dim_at(rotations):
+        return head_dim * math.log(original_max_pos / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(dim_at(beta_fast)), 0)
+    high = min(math.ceil(dim_at(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    extra = rope_frequencies(head_dim, theta)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: 0.1 · mscale · ln(factor) + 1 (1 at
+    factor ≤ 1).  DeepSeek-V3 multiplies the softmax scale by its square."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               inv_freq: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Rotate ``x`` (..., T, H, D) by per-token ``positions`` (..., T);
+    ``inv_freq`` replaces the plain frequencies (YaRN)."""
     d = x.shape[-1]
-    inv = rope_frequencies(d, theta)  # (D/2,)
+    inv = rope_frequencies(d, theta) if inv_freq is None else inv_freq  # (D/2,)
     ang = positions[..., :, None, None].astype(jnp.float32) * inv  # (..., T, 1, D/2)
     sin, cos = jnp.sin(ang), jnp.cos(ang)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

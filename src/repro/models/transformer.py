@@ -179,23 +179,28 @@ def apply_layer(
     kw = {}
     if spec.mixer in ("attn", "mla"):
         kw["positions"] = positions
-    if spec.mixer == "attn":
-        kw["mrope_positions"] = mrope_positions
+    if spec.mixer in ("attn", "mla"):
         kw["pages"] = pages
         kw["decode_attn"] = decode_attn
+    if spec.mixer == "attn":
+        kw["mrope_positions"] = mrope_positions
     if spec.mixer == "mla":
         kw["absorb"] = mla_absorb
     mix, new_cache = _MIXER_APPLY[spec.mixer](p["mixer"], cfg, hn, cache=cache, **kw)
     h = h + mix
     h = maybe_shard(h, "batch", "seq", None)
+    routes = None
     if spec.ffn == "dense":
         h = h + swiglu(p["ffn"], rmsnorm(p["ffn_norm"], h, eps=cfg.rms_eps))
+    elif spec.ffn == "moe" and cfg.holds_expert_share:
+        y, routes = moe.moe_apply_held(p["ffn"], cfg, rmsnorm(p["ffn_norm"], h, eps=cfg.rms_eps))
+        h = h + y
     elif spec.ffn == "moe":
         y, aux_moe = moe.moe_apply(p["ffn"], cfg, rmsnorm(p["ffn_norm"], h, eps=cfg.rms_eps))
         h = h + y
         aux = aux + aux_moe
     h = maybe_shard(h, "batch", "seq", None)
-    return h, new_cache, aux
+    return h, new_cache, aux, routes
 
 
 def init_layer_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, seq: int, dtype):
@@ -313,8 +318,14 @@ def forward(
     skip_logits: bool = False,
     pages: tuple | None = None,
     decode_attn: str = "off",
+    return_routes: bool = False,
 ):
-    """Returns (logits, aux_loss, new_cache[, hidden])."""
+    """Returns (logits, aux_loss, new_cache[, hidden][, routes]).
+
+    ``routes`` ((B, T) int32, with ``return_routes`` on a stack whose
+    expert layers hold a share of the experts): per token, the routed
+    (token, expert) pairs that landed on held experts, summed over the
+    expert layers."""
     cd = jnp.dtype(cfg.compute_dtype)
     B, T = tokens.shape
     if positions is None:
@@ -330,12 +341,14 @@ def forward(
 
     aux = jnp.zeros((), jnp.float32)
     new_caches = {} if cache is not None else None
+    count = cfg.holds_expert_share
+    routes = jnp.zeros((B, T), jnp.int32)
 
     for si, seg in enumerate(segments(cfg)):
         seg_params = params[f"seg{si}"]
 
         def body(carry, xs, seg=seg):
-            h, aux = carry
+            h, aux, *held = carry
             if cache is not None:
                 p_step, c_step = xs
             else:
@@ -343,7 +356,7 @@ def forward(
             new_c = {}
             for li, spec in enumerate(seg.unit):
                 c_in = c_step[f"l{li}"] if c_step is not None else None
-                h, c_out, a = apply_layer(
+                h, c_out, a, r = apply_layer(
                     p_step[f"l{li}"],
                     cfg,
                     spec,
@@ -356,12 +369,15 @@ def forward(
                     decode_attn=decode_attn,
                 )
                 aux = aux + a
+                if r is not None:
+                    held = [held[0] + r]
                 if c_out is not None:
                     new_c[f"l{li}"] = c_out
-            return (h, aux), (new_c if cache is not None else None)
+            return (h, aux, *held), (new_c if cache is not None else None)
 
         body = _remat_wrap(body, cfg) if cache is None else body
 
+        carry = (h, aux, routes) if count else (h, aux)
         if not cfg.scan_layers:
             # probe path: unroll so XLA cost_analysis counts every repeat
             new_slices = []
@@ -369,21 +385,23 @@ def forward(
                 p_r = jax.tree.map(lambda x: x[r], seg_params)
                 if cache is not None:
                     c_r = jax.tree.map(lambda x: x[r], cache[f"seg{si}"])
-                    (h, aux), c_out = body((h, aux), (p_r, c_r))
+                    carry, c_out = body(carry, (p_r, c_r))
                     new_slices.append(c_out)
                 else:
-                    (h, aux), _ = body((h, aux), p_r)
+                    carry, _ = body(carry, p_r)
             if cache is not None:
                 new_caches[f"seg{si}"] = jax.tree.map(
                     lambda *xs: jnp.stack(xs), *new_slices
                 )
         elif cache is not None:
-            (h, aux), seg_new_cache = jax.lax.scan(
-                body, (h, aux), (seg_params, cache[f"seg{si}"])
+            carry, seg_new_cache = jax.lax.scan(
+                body, carry, (seg_params, cache[f"seg{si}"])
             )
             new_caches[f"seg{si}"] = seg_new_cache
         else:
-            (h, aux), _ = jax.lax.scan(body, (h, aux), seg_params)
+            carry, _ = jax.lax.scan(body, carry, seg_params)
+        h, aux, *held = carry
+        routes = held[0] if count else routes
 
     h = rmsnorm(params["final_norm"], h, eps=cfg.rms_eps)
     if skip_logits:
@@ -395,6 +413,8 @@ def forward(
     out = (logits, aux, new_caches)
     if return_hidden:
         out = out + (h,)
+    if return_routes:
+        out = out + (routes,)
     return out
 
 
@@ -417,7 +437,7 @@ def mtp_hidden(params, cfg: ModelConfig, hidden, tokens, positions):
     )
     x = dense(p["proj"], x)
     spec = LayerSpec(mixer=cfg.mixer, ffn="dense" if cfg.moe is None else "moe")
-    x, _, aux = apply_layer(p["layer"], cfg, spec, x, positions=positions)
+    x, _, aux, _ = apply_layer(p["layer"], cfg, spec, x, positions=positions)
     x = rmsnorm(p["final_norm"], x, eps=cfg.rms_eps)
     return x, aux
 
@@ -526,31 +546,35 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, *, positions=None,
 # ----------------------------------------------------------------------------
 
 def _is_paged(x) -> bool:
-    return isinstance(x, cache_lib.PagedKVCache)
+    return isinstance(x, (cache_lib.PagedKVCache, cache_lib.PagedMLACache))
 
 
 def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int, dtype):
-    """Stacked per-segment ``PagedKVCache`` arenas (same tree shape as
-    ``init_cache`` so ``forward`` scans them identically).  Only pure-attn
-    stacks have a paged decode path — recurrent/MLA mixers keep their own
-    cache families."""
+    """Stacked per-segment page arenas (same tree shape as ``init_cache``
+    so ``forward`` scans them identically): a ``PagedKVCache`` for each
+    attention layer, a ``PagedMLACache`` (one latent row per position)
+    for each MLA layer.  Recurrent mixers have no paged decode path."""
     for spec in layer_specs(cfg):
-        if spec.mixer != "attn":
+        if spec.mixer not in ("attn", "mla"):
             raise ValueError(
-                f"paged decode supports attn-only stacks, got mixer "
+                f"paged decode supports attention and MLA stacks, got mixer "
                 f"{spec.mixer!r}"
             )
+
+    def arena(spec):
+        if spec.mixer == "mla":
+            m = cfg.mla
+            return cache_lib.paged_mla_cache_init(
+                n_pages, page_size, cache_lib.latent_width(m.kv_lora_rank, m.qk_rope_head_dim),
+                dtype)
+        return cache_lib.paged_kv_cache_init(
+            n_pages, page_size, cfg.num_kv_heads, cfg.head_dim, dtype)
+
     caches = {}
     for si, seg in enumerate(segs_of(cfg)):
         reps = []
         for _ in range(seg.repeats):
-            unit_c = {
-                f"l{li}": cache_lib.paged_kv_cache_init(
-                    n_pages, page_size, cfg.num_kv_heads, cfg.head_dim, dtype
-                )
-                for li in range(len(seg.unit))
-            }
-            reps.append(unit_c)
+            reps.append({f"l{li}": arena(spec) for li, spec in enumerate(seg.unit)})
         caches[f"seg{si}"] = _stack(reps)
     return caches
 
@@ -561,16 +585,22 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, cache, block, length,
 
     tokens: (n_slots, 1); block: (n_slots, pages_per_slot) physical page
     ids; length: (n_slots,) tokens already cached per slot.  Returns
-    (logits (n_slots, 1, V), new_cache).  Inactive slots (block row all
-    NULL_PAGE, length 0) compute garbage harmlessly — rows are
-    independent and their writes land in the null page.
+    (logits (n_slots, 1, V), new_cache[, routes (n_slots,)]): on a stack
+    whose expert layers hold a share of the experts
+    (``cfg.holds_expert_share``), each slot's routed pairs that landed on
+    held experts (``forward``).  Inactive slots (block row all NULL_PAGE,
+    length 0) compute garbage harmlessly — rows are independent and
+    their writes land in the null page.
     """
     positions = jnp.broadcast_to(length[:, None], tokens.shape)
-    logits, _, new_cache = forward(
+    out = forward(
         params, cfg, tokens, positions=positions, cache=cache,
         pages=(block, length), decode_attn=decode_attn,
+        return_routes=cfg.holds_expert_share,
     )
-    return logits, new_cache
+    if cfg.holds_expert_share:
+        return out[0], out[2], out[3][:, 0]
+    return out[0], out[2]
 
 
 def paged_insert_prompt(paged, dense, block_row, n_valid):
@@ -585,7 +615,11 @@ def paged_insert_prompt(paged, dense, block_row, n_valid):
 
     def insert_one(pg, dn):
         # the segment's stacked arenas in one scatter: dn.k is
-        # (repeats, 1, S, H_kv, D), pg.k (repeats, n_pages, H_kv, P, D)
+        # (repeats, 1, S, H_kv, D), pg.k (repeats, n_pages, H_kv, P, D);
+        # an MLA layer's latent rows are [c_kv ‖ k_rope], (repeats, S, W)
+        if isinstance(pg, cache_lib.PagedMLACache):
+            rows = jnp.concatenate([dn.c_kv[:, 0], dn.k_rope[:, 0]], axis=-1)
+            return cache_lib.paged_mla_write(pg, block_row, rows, n_valid)
         return cache_lib.paged_write(
             pg, block_row, dn.k[:, 0], dn.v[:, 0], n_valid
         )

@@ -167,7 +167,10 @@ class DecodeScheduler:
 def _build_step(cfg: ModelConfig, impl: str, temperature: float,
                 donate: bool):
     """The ONE compiled program of the decode plane: advance every slot a
-    token and sample the next on device (no (n_slots, V) transfer).
+    token and sample the next on device (no (n_slots, V) transfer).  For
+    a stack whose expert layers hold a share of the experts it returns a
+    third output, (n_slots,) int32: each slot's routed (token, expert)
+    pairs that landed on held experts, over the expert layers.
 
     Sampling keys are ``fold_in(key(seed), position)`` — a pure function
     of per-request data, so a request's sampled tokens are invariant to
@@ -175,8 +178,8 @@ def _build_step(cfg: ModelConfig, impl: str, temperature: float,
     """
 
     def step(params, tokens, cache, block, length, seeds):
-        logits, new_cache = tf.paged_decode_step(
-            params, cfg, tokens, cache, block, length, decode_attn=impl
+        logits, new_cache, *held = tf.paged_decode_step(
+            params, cfg, tokens, cache, block, length, decode_attn=impl,
         )
         lg = logits[:, 0, : cfg.vocab_size]
         if temperature > 0:
@@ -186,7 +189,7 @@ def _build_step(cfg: ModelConfig, impl: str, temperature: float,
             nxt = jax.vmap(jax.random.categorical)(keys, lg / temperature)
         else:
             nxt = jnp.argmax(lg, axis=-1)
-        return nxt.astype(jnp.int32), new_cache
+        return (nxt.astype(jnp.int32), new_cache, *held)
 
     return jax.jit(step, donate_argnames=("cache",) if donate else ())
 
@@ -195,8 +198,12 @@ class ContinuousLMEngine:
     """Slot-scheduled LM serving over a paged KV cache.
 
     Args:
-      cfg / params: an attention-only LM (``init_paged_cache`` rejects
-        recurrent/MLA stacks) and its parameters.
+      cfg / params: an attention or MLA LM, dense or with expert layers
+        (``init_paged_cache`` rejects recurrent stacks), and its
+        parameters.  MLA layers decode through a paged latent arena
+        (``PagedMLACache``, the paged MLA kernel on TPU); expert layers
+        that hold a share of the experts count each step's routes to
+        them (``serve/moe_routes_held`` of ``serve/moe_routes``).
       n_slots: in-flight sequences the compiled step advances together.
       page_size: tokens per physical KV page.
       max_seq: longest prompt+generation a request may need (sets the
@@ -258,6 +265,10 @@ class ContinuousLMEngine:
         self._seeds = np.zeros((n_slots,), np.int32)
         self._rid = 0
         self._lock = threading.RLock()
+        #: routed (token, expert) pairs a decoded token makes, over the
+        #: expert layers
+        self._routes_per_token = cfg.moe.top_k * sum(
+            s.ffn == "moe" for s in tf.layer_specs(cfg)) if cfg.holds_expert_share else 0
 
         donate = jax.default_backend() != "cpu"
         self._step = cached_program(
@@ -450,7 +461,7 @@ class ContinuousLMEngine:
                     kv_pages_table=self.sched.block.size,
                 ):
                     with self._span("serve/decode_step.inputs"):
-                        nxt, self._cache = self._step(
+                        nxt, self._cache, *held = self._step(
                             self.params,
                             jnp.asarray(self._last_tok[:, None]),
                             self._cache,
@@ -459,7 +470,8 @@ class ContinuousLMEngine:
                             jnp.asarray(self._seeds),
                         )
                     with self._span("serve/decode_step.wait"):
-                        nxt = np.asarray(jax.block_until_ready(nxt))
+                        nxt, *held = jax.block_until_ready((nxt, *held))
+                        nxt = np.asarray(nxt)
             except BaseException as e:
                 # fail every in-flight ticket NOW — a dead decode loop
                 # must not leave callers hanging until their timeout
@@ -473,6 +485,11 @@ class ContinuousLMEngine:
                 self.kernel_hits[self._impl] += len(active)
                 if self.tracer is not None:
                     self.tracer.count("serve/decode_tokens", len(active))
+                    if held:
+                        self.tracer.count("serve/moe_routes_held",
+                                          int(np.asarray(held[0])[active].sum()))
+                        self.tracer.count("serve/moe_routes",
+                                          len(active) * self._routes_per_token)
                 for s in active:
                     req = self.sched.slots[s]
                     req.tokens.append(int(nxt[s]))

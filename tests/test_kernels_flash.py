@@ -48,6 +48,23 @@ def test_flash_dtypes(dtype):
     assert float(jnp.max(jnp.abs(out.astype(jnp.float32) - exp.astype(jnp.float32)))) < tol
 
 
+@pytest.mark.parametrize("f32_products", [True, False], ids=["f32_products", "bf16_products"])
+def test_flash_bf16_at_training_width(f32_products):
+    """bf16 operands at a GQA training width (head 128, 4 query heads a KV
+    head, 256 positions) against the f32 oracle on the same values: the
+    default upcasts the products' inputs to f32, so only the bf16 output's
+    rounding is left (outputs here stay under 4, where a bf16 ulp is
+    2^-6); with the operands' dtype the softmax weights are also rounded
+    to bf16 (2^-9 relative), which stays inside that ulp."""
+    q, k, v = _mk(jax.random.key(11), 1, 256, 256, 8, 2, 128, jnp.bfloat16)
+    out = ops.flash_attention(q, k, v, f32_products=f32_products, bq=64, bk=64)
+    up = lambda a: a.astype(jnp.float32).transpose(0, 2, 1, 3)
+    exp = ref.attention_ref(up(q), up(k), up(v)).transpose(0, 2, 1, 3)
+    assert out.dtype == jnp.bfloat16
+    assert float(jnp.max(jnp.abs(exp))) < 4
+    assert float(jnp.max(jnp.abs(out.astype(jnp.float32) - exp))) < 2 ** -6
+
+
 def test_block_shape_independence():
     """Result must not depend on the BlockSpec tile size."""
     q, k, v = _mk(jax.random.key(3), 1, 128, 128, 4, 4, 32, jnp.float32)

@@ -92,6 +92,21 @@ def test_paged_decode_attention_at_qwen2_widths(one_chip):
     assert _has_kernel(c)
 
 
+def test_paged_mla_decode_at_deepseek_v3_widths(one_chip):
+    # the reasoning cell's latent arena: 64 slots of 128 pages of 64
+    # positions, rows of 576 values in 640 lanes, 128 query heads
+    n_slots, H, W, R, P, pps = 64, 128, 640, 512, 64, 128
+    n_pages = 1 + n_slots * pps
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    c = _compile(
+        lambda q, kv, b, n: da_ops.paged_mla_decode(
+            q, kv, b, n, value_width=R, scale=0.1, interpret=False),
+        sds((n_slots, H, W), jnp.float32), sds((n_pages, P, W), jnp.bfloat16),
+        sds((n_slots, pps), jnp.int32), sds((n_slots,), jnp.int32),
+    )
+    assert _has_kernel(c)
+
+
 def test_flash_attention_at_prefill_widths(one_chip):
     B, T, H, D = 1, 2048, 12, 128
     x = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=one_chip)
@@ -99,6 +114,20 @@ def test_flash_attention_at_prefill_widths(one_chip):
         lambda q, k, v: fa_ops.flash_attention(q, k, v, causal=True,
                                                interpret=False),
         x, x, x,
+    )
+    assert _has_kernel(c)
+
+
+def test_flash_attention_at_deepseek_v3_prefill_widths(one_chip):
+    # MLA's serving prefill at the reasoning cell's largest bucket: 128
+    # heads, 192-wide keys ([q_nope ‖ q_rope]), 128-wide values, 512 blocks
+    B, T, H = 1, 4096, 128
+    qk = jax.ShapeDtypeStruct((B, T, H, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((B, T, H, 128), jnp.bfloat16, sharding=one_chip)
+    c = _compile(
+        lambda q, k, v: fa_ops.flash_attention(q, k, v, causal=True, scale=0.1, bq=512,
+                                               bk=512, interpret=False),
+        qk, qk, v,
     )
     assert _has_kernel(c)
 
@@ -199,5 +228,52 @@ def test_paged_decode_step_qwen2_fits_one_chip(one_chip, monkeypatch):
     gathers, from_arena = _gathers_from(hlo, n_pages)
     assert gathers  # the embedding row and the block-table lookup
     assert not from_arena
+    mem = c.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def _reader_pattern(metric: str) -> str:
+    src = (Path(__file__).resolve().parents[1] / "chipbench" / "metrics"
+           / f"{metric}.py").read_text()
+    return re.search(r'^KERNEL = r"(.+)"$', src, re.M).group(1)
+
+
+def test_paged_decode_step_deepseek_v3_fits_one_chip(one_chip, monkeypatch):
+    """The engine's compiled step for the DeepSeek-V3 cell's cut (5
+    layers, 8 of 256 experts held, vocabulary 16,160, bf16) at the cell's
+    arena (64 slots, 8,192 positions, page 64): MLA decodes through the
+    paged MLA kernel, under the name the benchmark's reader matches and
+    the GQA reader's pattern would also match only by that name; the held
+    experts run as ragged dots; arguments plus temporaries fit one v5e
+    chip."""
+    from chipbench import harness
+    from repro.models import transformer as tf
+    from repro.serve import continuous
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ref = harness.config_module("deepseek-v3")
+    cfg = ref.program_config(harness.config("deepseek-v3"))
+    n_slots, page, max_seq = 64, 64, 8192
+    pps = max_seq // page
+    n_pages = 1 + n_slots * pps
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: tf.init_params(jax.random.key(0), cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: tf.init_paged_cache(cfg, n_pages, page, jnp.bfloat16)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    step = continuous._build_step(cfg, "pallas", 0.0, True)
+    c = step.lower(params, i32(n_slots, 1), cache, i32(n_slots, pps), i32(n_slots),
+                   i32(n_slots)).compile()
+    hlo = c.as_text()
+    kernels = {m.group(1) for m in re.finditer(
+        r"^\s*%(\S+) = .*custom_call_target=\"tpu_custom_call\"", hlo, re.M)}
+    mla_kernels = {k for k in kernels if re.search(_reader_pattern("mla_decode_roofline"), k)}
+    assert len(mla_kernels) == 2  # one in each segment's layer scan
+    assert {k for k in kernels if k.startswith("ragged-dot-none")}
+    assert not any(k.startswith("paged_decode_attention") for k in kernels)
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
